@@ -32,9 +32,12 @@ mkdir -p "$JAX_COMPILATION_CACHE_DIR"
 # -x still aborts the whole session on first failure under xdist;
 # --max-worker-restart 0 keeps a crashed worker from respawning past it,
 # and the cache provider is disabled so workers don't race on .pytest_cache.
+# --dist loadgroup keeps each xdist_group (tests/test_tpu_compile.py, which
+# loads the TPU compiler) on one worker.
 XDIST_ARGS=()
 if python -c "import xdist" >/dev/null 2>&1; then
-  XDIST_ARGS=(-n auto --max-worker-restart 0 -p no:cacheprovider)
+  XDIST_ARGS=(-n auto --dist loadgroup --max-worker-restart 0
+              -p no:cacheprovider)
 fi
 
 # Doctests of the documented public API. Scoped to the nine modules

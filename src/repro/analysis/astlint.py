@@ -6,7 +6,7 @@ classes *before* anything is traced:
 ``RPR001``  raw ``lax.psum`` inside a sharded-loss function (third
             positional arg named ``ctx`` or name containing
             ``sharded_loss``). Inside the pipeline's
-            ``shard_map(check_rep=False)`` region its transpose scales
+            ``shard_map(check_vma=False)`` region its transpose scales
             gradients by the model-axis size; use ``ctx.psum`` /
             ``psum_replicated`` instead.
 ``RPR002``  host synchronization (``.item()``, ``np.asarray``,
@@ -170,7 +170,7 @@ class _Linter(ast.NodeVisitor):
                     self._add("RPR001", sub,
                               "raw lax.psum in sharded loss "
                               f"`{fn.name}`; its transpose under "
-                              "check_rep=False scales gradients — use "
+                              "check_vma=False scales gradients — use "
                               "ctx.psum / psum_replicated")
 
     def _check_host_sync(self, fn) -> None:

@@ -251,8 +251,9 @@ class HloCost:
         default_factory=lambda: {k: 0.0 for k in COLLECTIVE_KINDS})
     coll_counts: Dict[str, float] = dataclasses.field(
         default_factory=lambda: {k: 0.0 for k in COLLECTIVE_KINDS})
-    # largest single collective instruction per kind (operand bytes, NOT
-    # multiplied by loop trip counts) — the "is there an all-gather of
+    # largest single collective per kind (operand bytes, NOT multiplied by
+    # loop trip counts; each operand of a combined variadic op on its
+    # own) — the "is there an all-gather of
     # full-parameter size in this step?" regression instrument
     coll_max: Dict[str, float] = dataclasses.field(
         default_factory=lambda: {k: 0.0 for k in COLLECTIVE_KINDS})
@@ -287,15 +288,32 @@ def _operand_bytes(ins: Instr, comp: Computation) -> int:
     return total
 
 
-def _collective_operand_bytes(ins: Instr, kind: str,
-                              comp: Computation) -> float:
-    result = _shape_list_bytes(ins.result_type)
-    g = _group_size(ins.attrs)
+def _operand_bytes_of(result: int, kind: str, g: int) -> float:
     if kind == "all-gather":
         return result / max(g, 1)
     if kind == "reduce-scatter":
         return result * g
     return float(result)  # all-reduce / permute / all-to-all
+
+
+def _collective_operand_bytes(ins: Instr, kind: str,
+                              comp: Computation) -> float:
+    return _operand_bytes_of(_shape_list_bytes(ins.result_type), kind,
+                             _group_size(ins.attrs))
+
+
+def _largest_collective_operand(ins: Instr, kind: str) -> float:
+    """Bytes of the largest single collective in ``ins``. XLA's combiners
+    merge independent collectives of one kind into one variadic op (a
+    tuple result, one element per merged op), so each element counts on
+    its own. Async ``-start`` tuples mix operand, result and context
+    buffers and count whole."""
+    g = _group_size(ins.attrs)
+    shapes = _SHAPE_RE.findall(ins.result_type)
+    if ins.op.endswith("-start") or len(shapes) < 2:
+        return _operand_bytes_of(_shape_list_bytes(ins.result_type), kind, g)
+    return max(_operand_bytes_of(_shape_bytes(d, s), kind, g)
+               for d, s in shapes)
 
 
 def analyze(text: str) -> HloCost:
@@ -319,8 +337,9 @@ def analyze(text: str) -> HloCost:
                 one = _collective_operand_bytes(ins, base_kind, comp)
                 cost.coll[base_kind] += mult * one
                 cost.coll_counts[base_kind] += mult
-                cost.coll_max[base_kind] = max(cost.coll_max[base_kind],
-                                               one)
+                cost.coll_max[base_kind] = max(
+                    cost.coll_max[base_kind],
+                    _largest_collective_operand(ins, base_kind))
                 cost.bytes += mult * _shape_list_bytes(ins.result_type)
                 continue
             if ins.op == "while":

@@ -1,14 +1,14 @@
 """Jaxpr-level shard-safety lint (rule namespace ``JXL``).
 
 The PR-5 bug class: a raw ``lax.psum`` inside a ``shard_map(...,
-check_rep=False)`` region transposes to *another* psum applied to an
+check_vma=False)`` region transposes to *another* psum applied to an
 already-replicated cotangent, silently scaling every gradient by the
 mesh-axis size. The safe patterns (``train.grad.psum_replicated`` /
 ``_slice_replicated``) route the collective through a ``custom_vjp`` whose
 backward rule is shaped by hand. This module makes the distinction
 checkable:
 
-``JXL001``  raw ``psum`` / ``all_gather`` inside a ``check_rep=False``
+``JXL001``  raw ``psum`` / ``all_gather`` inside a ``check_vma=False``
             shard_map region that is not under a ``custom_vjp`` boundary.
             Two detection modes, because AD *inlines* custom_vjp bodies
             (a grad trace of a protected and a raw loss are structurally
@@ -36,16 +36,16 @@ import dataclasses
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 RULES = {
-    "JXL001": ("raw collective under shard_map(check_rep=False) outside a "
+    "JXL001": ("raw collective under shard_map(check_vma=False) outside a "
                "custom_vjp boundary (gradient-scaling bug class)"),
     "JXL002": "collective bound to the wrong mesh axis",
     "JXL003": "abstract call signature churn (recompilation)",
 }
 
-# primitives whose transpose under check_rep=False replicated cotangents
+# primitives whose transpose under check_vma=False replicated cotangents
 # produces the M-times gradient scaling
 _RAW_COLLECTIVES = ("psum", "all_gather")
 # reduction-flavored vs neighbor-shift-flavored collectives for JXL002
@@ -105,7 +105,7 @@ def _axis_names(params: Dict[str, Any]) -> Tuple[str, ...]:
 
 def _eqn_is_norep_shardmap(eqn) -> bool:
     return (eqn.primitive.name == "shard_map"
-            and eqn.params.get("check_rep") is False)
+            and eqn.params.get("check_vma") is False)
 
 
 def _eqn_is_custom_vjp(eqn) -> bool:
@@ -139,7 +139,7 @@ def lint_jaxpr(jaxpr: Any, *,
                     findings.append(Finding(
                         "JXL001",
                         f"raw `{name}` over {axes or '<?>'} inside "
-                        "shard_map(check_rep=False); route it through "
+                        "shard_map(check_vma=False); route it through "
                         "psum_replicated / a custom_vjp or its transpose "
                         "will scale gradients by the axis size",
                         ctx.path))
@@ -175,7 +175,7 @@ def lint_fn(fn: Callable, *args: Any, **lint_kwargs: Any) -> List[Finding]:
 
 
 def _psum_accounting(jaxpr: Any) -> Tuple[Dict[Tuple, int], Dict[Tuple, int]]:
-    """Shape-multiset accounting of psums inside check_rep=False regions:
+    """Shape-multiset accounting of psums inside check_vma=False regions:
 
     returns ``(psum_shapes, slice_input_shapes)`` — output-shape -> count
     for every psum, and input-shape -> count for every *slice-like*
@@ -242,7 +242,7 @@ def lint_grad_psums(forward_fn: Callable, grad_fn: Callable,
             findings.append(Finding(
                 "JXL001",
                 f"grad trace has {g} psum(s) of shape {shape} inside "
-                f"check_rep=False regions but the forward trace only "
+                f"check_vma=False regions but the forward trace only "
                 f"accounts for {allowed} (forward replays + slice "
                 "transposes); the surplus is a raw collective's transpose "
                 "replicating cotangents (gradient-scaling bug)"))

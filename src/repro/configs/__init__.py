@@ -6,8 +6,9 @@ CPU-smoke variant (<=2 layers, d_model<=512, <=4 experts).
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib
-from typing import Dict, List
+from typing import Dict, List, Optional, Tuple
 
 from repro.configs.base import (ArchConfig, InputShape, INPUT_SHAPES,
                                 ModelConfig, ParallelConfig)
@@ -44,6 +45,54 @@ def get_arch(arch_id: str) -> ArchConfig:
     return importlib.import_module(_MODULES[arch_id]).FULL
 
 
+def cut_arch(arch: ArchConfig, *, layers: Optional[int] = None,
+             vocab: Optional[int] = None) -> Tuple[ArchConfig, Tuple[str, ...]]:
+    """Size a published config for one chip without touching any width.
+
+    ``layers`` keeps the first N of the published layers (a depth cut: the
+    rest would be further pipeline stages). ``vocab`` keeps the first V
+    rows of the vocabulary, at least one eighth of the published count: a
+    sliced vocabulary is a smaller vocabulary, so token ids are drawn from
+    the slice and logits and the loss are over it. Returns the cut config
+    and one human-readable line per cut made."""
+    m = arch.model
+    repl, cuts = {}, []
+    if layers is not None:
+        if not 1 <= layers <= m.n_layers:
+            raise ValueError(f"--layers {layers} outside [1, {m.n_layers}] "
+                             f"for {m.arch_id}")
+        if layers < m.n_layers:
+            repl["n_layers"] = layers
+            cuts.append(f"n_layers {m.n_layers} -> {layers}")
+    if vocab is not None:
+        floor = -(-m.vocab_size // 8)
+        if not floor <= vocab <= m.vocab_size:
+            raise ValueError(f"--vocab {vocab} outside [{floor}, "
+                             f"{m.vocab_size}] (at least 1/8 of the "
+                             f"published vocabulary of {m.arch_id})")
+        if vocab < m.vocab_size:
+            repl["vocab_size"] = vocab
+            cuts.append(f"vocab_size {m.vocab_size} -> {vocab}")
+    if not repl:
+        return arch, ()
+    return (dataclasses.replace(arch, model=dataclasses.replace(m, **repl)),
+            tuple(cuts))
+
+
+def sized_arch(arch_id: str, full: bool, layers: Optional[int] = None,
+               vocab: Optional[int] = None
+               ) -> Tuple[ArchConfig, Tuple[str, ...]]:
+    """The arch config a launch runs: the reduced preset, or the published
+    one with the ``--layers``/``--vocab`` cuts of :func:`cut_arch` (only
+    with ``--full``). Raises ValueError on a cut it cannot make."""
+    if not full:
+        if layers is not None or vocab is not None:
+            raise ValueError("--layers/--vocab cut the published config; "
+                             "pass --full")
+        return get_reduced(arch_id), ()
+    return cut_arch(get_arch(arch_id), layers=layers, vocab=vocab)
+
+
 def get_reduced(arch_id: str) -> ArchConfig:
     if arch_id not in _MODULES:
         raise KeyError(f"unknown arch {arch_id!r}; have {list_archs()}")
@@ -51,4 +100,5 @@ def get_reduced(arch_id: str) -> ArchConfig:
 
 
 __all__ = ["ArchConfig", "ModelConfig", "ParallelConfig", "InputShape",
-           "INPUT_SHAPES", "SKIPS", "list_archs", "get_arch", "get_reduced"]
+           "INPUT_SHAPES", "SKIPS", "list_archs", "get_arch", "get_reduced",
+           "cut_arch", "sized_arch"]

@@ -47,7 +47,6 @@ import dataclasses
 from typing import Any, Callable, Dict, Optional
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import numpy as np
@@ -151,28 +150,36 @@ def _with_axis_execution(opt: "DecentralizedOptimizer", mesh: Any,
     base_init, base_step, base_round = opt.init, opt.step, opt.round
 
     def init(params: PyTree) -> Any:
-        return shard_over_workers(base_init(params), mesh, K, axis_name,
+        # built straight onto the worker axis, so no device ever holds all
+        # K workers' state; on a 2D mesh each worker's rows are then split
+        # over the model axis by a reshard (compiling that split into the
+        # init itself takes minutes)
+        specs = worker_pspec_tree(jax.eval_shape(base_init, params), K,
+                                  axis_name)
+        state = jax.jit(base_init, out_shardings=jax.tree_util.tree_map(
+            lambda s: NamedSharding(mesh, s), specs))(params)
+        return shard_over_workers(state, mesh, K, axis_name,
                                   model_axis=model_axis)
 
     def step(state: Any, grads: PyTree) -> Any:
         state_specs = worker_pspec_tree(state, K, axis_name,
                                         model_axis=model_axis)
-        return shard_map(
+        return jax.shard_map(
             base_step, mesh=mesh,
             in_specs=(state_specs,
                       worker_pspec_tree(grads, K, axis_name,
                                         model_axis=model_axis)),
-            out_specs=state_specs, check_rep=False)(state, grads)
+            out_specs=state_specs, check_vma=False)(state, grads)
 
     def round_(state: Any, grad_fn: Callable, batches: Any) -> Any:
         state_specs = worker_pspec_tree(state, K, axis_name,
                                         model_axis=model_axis)
-        return shard_map(
+        return jax.shard_map(
             lambda s, b: base_round(s, grad_fn, b), mesh=mesh,
             in_specs=(state_specs,
                       worker_pspec_tree(batches, K, axis_name,
                                         worker_dim=1)),
-            out_specs=state_specs, check_rep=False)(state, batches)
+            out_specs=state_specs, check_vma=False)(state, batches)
 
     sharded_vag = None
     if model_axis is not None:
@@ -189,11 +196,11 @@ def _with_axis_execution(opt: "DecentralizedOptimizer", mesh: Any,
         def sharded_vag(local_vag: Callable, state: Any, batch: PyTree):
             buf_spec = P(axis_name, model_axis)
             batch_specs = worker_pspec_tree(batch, K, axis_name)
-            return shard_map(
+            return jax.shard_map(
                 local_vag, mesh=mesh,
                 in_specs=(buf_spec, batch_specs),
                 out_specs=(P(axis_name), buf_spec),
-                check_rep=False)(state.buf, batch)
+                check_vma=False)(state.buf, batch)
 
     return dataclasses.replace(
         opt, init=init, step=step,

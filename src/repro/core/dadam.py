@@ -863,8 +863,9 @@ def _step_packed_fused(state: PackedDAdamState, grads: Any,
                        ) -> PackedDAdamState:
     """Comm-step fast path: Adam half-step AND gossip mix in one VMEM
     pass over the resident buffers (``kernels.gossip.gossip_adam_mix``) —
-    the half-stepped parameter stack never round-trips HBM. Bit-for-bit
-    the two-pass (fused_adam → gossip_mix) sequence; non-comm steps under
+    the half-stepped parameter stack never round-trips HBM. Matches the
+    two-pass (fused_adam → gossip_mix) sequence: m and v bit for bit,
+    params within one rounding per mixed term; non-comm steps under
     period > 1 run the plain fused_adam branch of the same cond."""
     from repro.kernels import ops
 

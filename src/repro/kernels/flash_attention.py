@@ -19,9 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across jax versions: CompilerParams (new) vs TPUCompilerParams (old)
-_COMPILER_PARAMS = getattr(pltpu, 'CompilerParams', None) \
-    or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -130,7 +127,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -35,9 +35,12 @@ no per-step pack/unpack, no per-leaf tree_map launches:
     its own block AND each neighbor block straight from (p, g, m, v) and
     mixes them in registers, so the half-stepped parameter stack is never
     written to (or re-read from) HBM at all. The half-step result is
-    rounded through the parameter dtype before mixing, which keeps the
-    output bit-for-bit identical to the stored-then-reloaded two-pass
-    sequence. The Adam math for neighbor blocks is redundant compute
+    rounded through the parameter dtype before mixing, as the
+    stored-then-reloaded two-pass sequence does: m and v match it bit for
+    bit, and the params within one rounding of each mixed half-step
+    (the compiler may contract the Adam multiply-adds differently in
+    this kernel body than in fused_adam's). The Adam math for neighbor
+    blocks is redundant compute
     ((deg + 1)× per block), but the kernel is memory-bound: trading VPU
     flops for one full HBM round-trip of the parameter stack wins.
 
@@ -176,7 +179,8 @@ def _gossip_adam_kernel(*refs, self_weight: float,
 
     def half_step(p_ref, g_ref, m_ref, v_ref):
         # identical ops, order and constants as fused_adam._adam_kernel —
-        # that is what pins the fused path bitwise to the two-pass one
+        # that is what keeps the fused path within one rounding of the
+        # two-pass one
         g = g_ref[...].astype(jnp.float32)
         p = p_ref[...]
         if weight_decay:
@@ -215,7 +219,8 @@ def gossip_adam_mix(p: jax.Array, g: jax.Array, m: jax.Array,
     recomputed in VMEM from the neighbor's (p, g, m, v) blocks via
     shifted BlockSpec index maps (same shift arithmetic as
     ``gossip_mix``), with the half-step rounded through the parameter
-    dtype before the f32 mix — bit-for-bit the two-pass result.
+    dtype before the f32 mix. m and v are bit for bit the two-pass
+    result; each mixed param is within ``2 eps sum_j |w_j p_j|`` of it.
     """
     K, rows = _check_buf(p, block_rows)
     for name, b in (("g", g), ("m", m), ("v", v)):

@@ -295,18 +295,22 @@ def pack(tree: PyTree, spec: PackSpec, dtype: Any = None) -> jax.Array:
     dt = jnp.dtype(dtype) if dtype is not None else jnp.result_type(*leaves)
     pads = _segment_pads(spec)
     if spec.stacked:
-        M = spec.row_shards
         parts = []
         for l, pad in zip(leaves, pads):
             flat = l.reshape(spec.k, -1).astype(dt)
             if pad:
                 flat = jnp.pad(flat, ((0, 0), (0, pad)))
-            # row-sharded layout: split this leaf's segment into M equal
-            # chunks so concatenation below interleaves leaves per shard
-            parts.append(flat.reshape(spec.k, M, -1) if M > 1 else flat)
-        axis = 2 if M > 1 else 1
+            parts.append(flat)
+        if spec.row_shards > 1:
+            # row-sharded layout: shard block j is chunk j of every leaf's
+            # segment, in leaf order. Lane-aligned slices, not a
+            # (K, M, chunk) reshape: on a TPU that reshape relayouts the
+            # whole buffer and takes minutes to compile
+            parts = [f[:, j * c:(j + 1) * c]
+                     for j in range(spec.row_shards)
+                     for f, c in zip(parts, _shard_chunks(spec))]
         flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts,
-                                                                axis=axis)
+                                                                axis=1)
         return flat.reshape(spec.k, spec.rows, LANE)
     parts = []
     for l, pad in zip(leaves, pads):
@@ -321,7 +325,9 @@ def _unpack_one_row(row: jax.Array, spec: PackSpec) -> PyTree:
     buffer — into the per-worker param pytree (leaf shapes without the
     leading K dim). Shared by :func:`unpack_worker` / :func:`unpack_mean`."""
     per_worker = tuple(s[1:] for s in spec.shapes)
-    if spec.row_shards > 1:
+    if spec.row_shards == 1 and spec.leaf_aligned:
+        leaves = _leaf_rows(row, spec, per_worker)
+    elif spec.row_shards > 1:
         flat = row.reshape(spec.row_shards, -1)
         leaves = [
             flat[:, o:o + c].reshape(-1)[:sz].astype(dt).reshape(shape)
@@ -382,10 +388,30 @@ def unpack_mean(buf: jax.Array, spec: PackSpec) -> PyTree:
     return _unpack_one_row(jnp.mean(buf, axis=0), spec)
 
 
+def _leaf_rows(buf: jax.Array, spec: PackSpec, shapes) -> list:
+    """Leaves of a leaf-aligned buffer, each cut out of its own row range
+    (``buf`` is ``(..., rows, LANE)``). Slicing rows keeps the buffer in
+    its tiled ``(rows, LANE)`` layout: only each leaf is reshaped, never
+    the whole buffer, which on a TPU would be a relayout copy of all of
+    it (and a second one in the gradient's transpose)."""
+    lead = buf.shape[:-2]
+    out = []
+    for (r0, r1), sz, dt, shape in zip(leaf_row_ranges(spec), spec.sizes,
+                                       spec.dtypes, shapes):
+        seg = buf[..., r0:r1, :].reshape(lead + (-1,))
+        if sz != (r1 - r0) * LANE:
+            seg = seg[..., :sz]
+        out.append(seg.astype(dt).reshape(shape))
+    return out
+
+
 def unpack(buf: jax.Array, spec: PackSpec) -> PyTree:
     """Exact inverse of ``pack``: strip padding, split, restore per-leaf
     shape and dtype."""
     if spec.stacked:
+        if spec.row_shards == 1 and spec.leaf_aligned:
+            return jax.tree_util.tree_unflatten(
+                spec.treedef, _leaf_rows(buf, spec, spec.shapes))
         if spec.row_shards > 1:
             # inverse of the row-sharded layout: gather each leaf's M
             # chunks (one per shard block), re-join, strip padding

@@ -24,10 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across jax versions: CompilerParams (new) vs TPUCompilerParams (old)
-_COMPILER_PARAMS = getattr(pltpu, 'CompilerParams', None) \
-    or pltpu.TPUCompilerParams
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sf_ref,
                 state_ref, *, chunk: int, n_chunks: int):
@@ -95,7 +91,7 @@ def rwkv_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((B, H, D, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(rt, kt, vt, wt, u, state)

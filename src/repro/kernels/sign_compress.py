@@ -6,7 +6,8 @@ sign operator [4], made delta-contractive by the L1 scale), then applies
 ``xhat += scale * q`` locally. Two kernels:
 
   1. ``_absmean_kernel`` — grid reduction producing per-block |delta| sums
-     (one VMEM pass over x, xhat);
+     (one VMEM pass over x, xhat); each grid step writes its sum at its
+     own index of one whole-array SMEM output;
   2. ``_apply_kernel``   — given the final scale, emits the int8 payload and
      the updated xhat in one fused pass (the int8 tensor is what the
      runtime ppermutes to neighbors — 1 byte/elem on the wire).
@@ -20,6 +21,7 @@ vmap-per-worker semantics of the reference CD-Adam encode path.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -30,17 +32,32 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.pack import BLOCK_ROWS, LANE
 
 
-def _absmean_kernel(x_ref, h_ref, out_ref):
+def _absmean_kernel(x_ref, h_ref, out_ref, *, stacked: bool = False):
+    # out_ref is the whole 1-D partials array in SMEM, one entry per grid
+    # step in row-major grid order; every step revisits it, so the steps
+    # run in order
+    i = (pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
+         if stacked else pl.program_id(0))
     d = x_ref[...].astype(jnp.float32) - h_ref[...].astype(jnp.float32)
-    out_ref[0, 0] = jnp.sum(jnp.abs(d))
+    out_ref[i] = jnp.sum(jnp.abs(d))
 
 
-def _apply_kernel(x_ref, h_ref, scale_ref, q_ref, ho_ref):
+def _apply_kernel(x_ref, h_ref, scale_ref, q_ref, ho_ref, *,
+                  stacked: bool = False):
+    # scale_ref is the whole (K,) scale vector in SMEM: worker k's scale
+    # sits at grid index k of the stacked variant, at 0 of the flat one
+    scale = scale_ref[pl.program_id(0) if stacked else 0]
     d = x_ref[...].astype(jnp.float32) - h_ref[...].astype(jnp.float32)
     s = jnp.sign(d)
     q_ref[...] = s.astype(jnp.int8)
     ho_ref[...] = (h_ref[...].astype(jnp.float32)
-                   + scale_ref[0, 0] * s).astype(ho_ref.dtype)
+                   + scale * s).astype(ho_ref.dtype)
+
+
+# the scale operand and the partials output: whole 1-D arrays in SMEM —
+# Mosaic can't load a scalar directly from an ANY-space ref, and a blocked
+# SMEM spec is refused unless the block is the whole array
+_SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def sign_compress(x: jax.Array, hat: jax.Array, *,
@@ -66,30 +83,25 @@ def sign_compress(x: jax.Array, hat: jax.Array, *,
         _absmean_kernel,
         grid=grid,
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], 1), jnp.float32),
+        out_specs=_SMEM_SPEC,
+        out_shape=jax.ShapeDtypeStruct(grid, jnp.float32),
         interpret=interpret,
     )(xx, hh)
     # padded entries are x=0, hat=0 -> contribute 0 to the sum; divide by
     # the true element count.
     scale = jnp.sum(partials) / n
-    scale2d = scale.reshape(1, 1)
 
     q, hat_new = pl.pallas_call(
         _apply_kernel,
         grid=grid,
-        in_specs=[spec, spec,
-                  # scalar operand: SMEM, not ANY — Mosaic can't load
-                  # directly from an ANY-space ref on real TPUs
-                  pl.BlockSpec((1, 1), lambda i: (0, 0),
-                               memory_space=pltpu.SMEM)],
+        in_specs=[spec, spec, _SMEM_SPEC],
         out_specs=[spec, spec],
         out_shape=[
             jax.ShapeDtypeStruct(xx.shape, jnp.int8),
             jax.ShapeDtypeStruct(hh.shape, hat.dtype),
         ],
         interpret=interpret,
-    )(xx, hh, scale2d)
+    )(xx, hh, scale.reshape(1))
 
     def unprep(t, shape):
         flat = t.reshape(-1)
@@ -101,19 +113,6 @@ def sign_compress(x: jax.Array, hat: jax.Array, *,
 
 
 # --------------------------- stacked-K variant ------------------------------
-
-
-def _absmean_stacked_kernel(x_ref, h_ref, out_ref):
-    d = x_ref[...].astype(jnp.float32) - h_ref[...].astype(jnp.float32)
-    out_ref[0, 0] = jnp.sum(jnp.abs(d))
-
-
-def _apply_stacked_kernel(x_ref, h_ref, scale_ref, q_ref, ho_ref):
-    d = x_ref[...].astype(jnp.float32) - h_ref[...].astype(jnp.float32)
-    s = jnp.sign(d)
-    q_ref[...] = s.astype(jnp.int8)
-    ho_ref[...] = (h_ref[...].astype(jnp.float32)
-                   + scale_ref[0, 0] * s).astype(ho_ref.dtype)
 
 
 def sign_compress_stacked(x: jax.Array, hat: jax.Array, *,
@@ -172,13 +171,14 @@ def sign_compress_stacked(x: jax.Array, hat: jax.Array, *,
     spec = pl.BlockSpec((1, block_rows, LANE), lambda k, i: (k, i, 0))
 
     partials = pl.pallas_call(
-        _absmean_stacked_kernel,
+        functools.partial(_absmean_kernel, stacked=True),
         grid=grid,
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1, 1), lambda k, i: (k, i)),
-        out_shape=jax.ShapeDtypeStruct((K, grid[1]), jnp.float32),
+        out_specs=_SMEM_SPEC,
+        out_shape=jax.ShapeDtypeStruct((K * grid[1],), jnp.float32),
         interpret=interpret,
     )(xx, hh)
+    partials = partials.reshape(grid)
     # padded entries are x=0, hat=0 -> contribute 0; divide by the true
     # per-worker element count. On a 2D mesh the partial sums of the other
     # model shards join via psum, so the scale is the global per-leaf L1
@@ -187,21 +187,18 @@ def sign_compress_stacked(x: jax.Array, hat: jax.Array, *,
     if reduce_axis is not None:
         local = jax.lax.psum(local, reduce_axis)
     scale = local / n_true
-    scale2d = scale.reshape(K, 1)
 
     q, hat_new = pl.pallas_call(
-        _apply_stacked_kernel,
+        functools.partial(_apply_kernel, stacked=True),
         grid=grid,
-        in_specs=[spec, spec,
-                  pl.BlockSpec((1, 1), lambda k, i: (k, 0),
-                               memory_space=pltpu.SMEM)],
+        in_specs=[spec, spec, _SMEM_SPEC],
         out_specs=[spec, spec],
         out_shape=[
             jax.ShapeDtypeStruct(xx.shape, jnp.int8),
             jax.ShapeDtypeStruct(hh.shape, hat.dtype),
         ],
         interpret=interpret,
-    )(xx, hh, scale2d)
+    )(xx, hh, scale)
 
     def unprep(t, shape):
         flat = t.reshape(K, -1)

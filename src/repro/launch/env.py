@@ -8,6 +8,13 @@ is safe (and intended) to import at the very top of a driver script:
     from repro.launch import env
     env.setup()          # then `import jax`
 
+``setup()`` also fixes where JAX keeps its persistent compilation
+cache: a ``JAX_COMPILATION_CACHE_DIR`` the caller exported is left alone,
+and otherwise it is set to ``<checkout>/.jax_cache`` — a fixed path
+(git-ignored, the one ``scripts/tier1.sh`` uses), so a second run of the
+same program finds what the first one compiled. No other cache location
+is set anywhere in the code.
+
 Two rules govern every helper here:
 
 * **append, never clobber** — a pre-set ``XLA_FLAGS`` survives intact;
@@ -20,9 +27,14 @@ Two rules govern every helper here:
 from __future__ import annotations
 
 import os
+import pathlib
 from typing import Mapping, MutableMapping, Optional, Sequence
 
 HOST_DEVICE_FLAG = "--xla_force_host_platform_device_count"
+CACHE_DIR_VAR = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache: this file is <checkout>/src/repro/launch/env.py
+DEFAULT_CACHE_DIR = str(pathlib.Path(__file__).resolve().parents[3]
+                        / ".jax_cache")
 
 # Async-collective + latency-hiding-scheduler flags: let XLA issue
 # collective-permute-start early and schedule independent fused-Adam
@@ -122,6 +134,18 @@ def enable_async_collectives(*, env: Optional[MutableMapping[str, str]]
     return ensure_xla_flags(ASYNC_COLLECTIVE_FLAGS, env=env)
 
 
+def ensure_compile_cache(env: Optional[MutableMapping[str, str]] = None
+                         ) -> str:
+    """Point JAX's persistent compilation cache at
+    :data:`DEFAULT_CACHE_DIR` unless ``JAX_COMPILATION_CACHE_DIR`` is
+    already set (then it is left as it is). Returns the directory in
+    effect."""
+    env = os.environ if env is None else env
+    if not env.get(CACHE_DIR_VAR):
+        env[CACHE_DIR_VAR] = DEFAULT_CACHE_DIR
+    return env[CACHE_DIR_VAR]
+
+
 def setup(host_devices: Optional[int] = None, *,
           async_collectives: bool = True,
           platform: Optional[str] = None,
@@ -131,6 +155,7 @@ def setup(host_devices: Optional[int] = None, *,
     env = os.environ if env is None else env
     if platform is not None:
         env.setdefault("JAX_PLATFORMS", platform)
+    ensure_compile_cache(env=env)
     n = ensure_host_devices(host_devices, env=env)
     if async_collectives:
         enable_async_collectives(env=env)

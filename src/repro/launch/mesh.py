@@ -12,21 +12,29 @@ from __future__ import annotations
 import warnings
 
 import jax
+from jax.sharding import AxisType
 
 PEAK_FLOPS = 197e12       # bf16 per chip
 HBM_BW = 819e9            # bytes/s per chip
 ICI_BW = 50e9             # bytes/s per link
 
 
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated
+    shardings), the semantics this code is written for; newer JAX
+    defaults to ``Explicit`` axes, which type every array's sharding."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (host) devices exist — used by tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def make_worker_mesh(workers: int, *, model_parallel: int = 1,
@@ -51,8 +59,8 @@ def make_worker_mesh(workers: int, *, model_parallel: int = 1,
                       "model_parallel=", DeprecationWarning, stacklevel=2)
     m = model_parallel if model is None else model
     if m > 1:
-        return jax.make_mesh((workers, m), (axis_name, "model"))
-    return jax.make_mesh((workers,), (axis_name,))
+        return _mesh((workers, m), (axis_name, "model"))
+    return _mesh((workers,), (axis_name,))
 
 
 def n_chips(mesh) -> int:

@@ -1,20 +1,28 @@
 """Training driver: decentralized D-Adam / CD-Adam training of any
-registered architecture on host devices.
+registered architecture.
 
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b \
-        --reduced --workers 4 --steps 50 --optimizer cd-adam --period 4
+        --workers 4 --steps 50 --optimizer cd-adam --period 4
 
-Uses the reduced config by default on CPU; pass --full on real hardware.
-Checkpoints every --ckpt-every steps via repro.checkpoint.
+Without ``--full`` it trains the arch's reduced preset (the CPU test
+size: ``JAX_PLATFORMS=cpu``, Pallas kernels in interpret mode). ``--full``
+takes the published widths; ``--layers N`` and ``--vocab V`` then cut
+depth and vocabulary to what one chip holds, leaving every width as
+published, and the run prints the cuts it made. ``main(argv)`` runs
+in-process and returns a :class:`TrainRun` (what ``chip_smoke.py``
+drives). Checkpoints every --ckpt-every steps via repro.checkpoint.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Any, Optional, Sequence, Tuple
 
 if __name__ == "__main__":
-    # host-device count + async-collective XLA flags must land BEFORE
-    # jax initializes; repro.launch.env appends to any pre-set XLA_FLAGS
+    # host-device count, compile cache and async-collective XLA flags
+    # must land BEFORE jax initializes; repro.launch.env appends to any
+    # pre-set XLA_FLAGS
     from repro.launch import env as _env
     _env.setup()
 
@@ -22,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import save
-from repro.configs import get_arch, get_reduced, list_archs
+from repro.configs import list_archs, sized_arch
 from repro.core import make_optimizer
 from repro.data import lm_batch
 from repro.launch.mesh import make_worker_mesh
@@ -31,8 +39,13 @@ from repro.models import build_model
 from repro.train import DecentralizedTrainer
 
 
+# seed of the random init; the batch stream draws from BATCH_SEED
+SEED = 0
+BATCH_SEED = 42
+
+
 def make_batch_iter(cfg, K: int, per_worker: int, seq: int, skew: float):
-    key = jax.random.PRNGKey(42)
+    key = jax.random.PRNGKey(BATCH_SEED)
     t = 0
     while True:
         kt = jax.random.fold_in(key, t)
@@ -51,11 +64,40 @@ def make_batch_iter(cfg, K: int, per_worker: int, seq: int, skew: float):
         t += 1
 
 
-def main() -> None:
+@dataclasses.dataclass
+class TrainRun:
+    """What one ``main(argv)`` run leaves behind, for in-process callers.
+
+    ``state`` is the live optimizer state (the trainer donates the one
+    it was given each step); ``first_step_s`` is the wall time of step 1,
+    compilation included, and ``steady_ms`` the mean of the later
+    steps."""
+    args: argparse.Namespace
+    cfg: Any
+    cuts: Tuple[str, ...]
+    trainer: DecentralizedTrainer
+    state: Any
+    log: Any
+    n_params: int
+    first_step_s: float
+    steady_ms: Optional[float]
+
+
+def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=list_archs())
     ap.add_argument("--full", action="store_true",
-                    help="full config (needs real hardware)")
+                    help="published widths (needs a chip)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="with --full: keep the first N layers (depth cut)")
+    ap.add_argument("--vocab", type=int, default=None,
+                    help="with --full: keep the first V vocabulary rows "
+                         "(>= 1/8 of the published count); batches draw "
+                         "ids from the slice and the loss is over it")
+    ap.add_argument("--compute-dtype", default=None,
+                    choices=["bfloat16", "float32"],
+                    help="dtype of the model's activations and matmul "
+                         "inputs (default: the config's)")
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=2, help="per worker")
@@ -130,9 +172,16 @@ def main() -> None:
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    arch = get_arch(args.arch) if args.full else get_reduced(args.arch)
+    try:
+        arch, cuts = sized_arch(args.arch, args.full, args.layers,
+                                args.vocab)
+    except ValueError as e:
+        ap.error(str(e))
+    if args.compute_dtype is not None:
+        arch = dataclasses.replace(arch, model=dataclasses.replace(
+            arch.model, compute_dtype=jnp.dtype(args.compute_dtype)))
     cfg = arch.model
     api = build_model(cfg)
     mesh = None
@@ -184,15 +233,23 @@ def main() -> None:
                                    microbatch=args.microbatch, plan=plan,
                                    damping=damping,
                                    sharded_loss=getattr(api, "sharded_loss",
-                                                        None))
-    params = api.init(jax.random.PRNGKey(0))
-    state = trainer.init(params)
+                                                        None),
+                                   donate=True)
+    params = api.init(jax.random.PRNGKey(SEED))
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
+    state = trainer.init(params)
+    del params
+    print(f"[train] {args.arch} ({'full' if args.full else 'reduced'}) "
+          f"cuts: {', '.join(cuts) if cuts else 'none'}")
     print(f"[train] {args.arch} ({'full' if args.full else 'reduced'}) "
           f"N={n_params/1e6:.1f}M x {args.workers} workers "
           f"opt={args.optimizer} p={args.period} "
-          f"topo={args.topology} backend={args.backend} comm={args.comm}"
+          f"topo={args.topology} backend={args.backend} comm={args.comm} "
+          f"compute={jnp.dtype(cfg.compute_dtype).name}"
           + (" overlap" if args.overlap else ""))
+    state_gb = sum(x.nbytes for x in jax.tree_util.tree_leaves(state)) / 1e9
+    print(f"[train] resident optimizer state {state_gb:.2f} GB "
+          f"({args.workers} workers)")
     if args.comm == "axis":
         print(f"[train] worker mesh: {tuple(mesh.shape.items())} — state "
               f"sharded one worker per slot; gossip = ppermute over "
@@ -221,21 +278,29 @@ def main() -> None:
               f"signal); one compiled step across all levels")
 
     it = make_batch_iter(cfg, args.workers, args.batch, args.seq, args.skew)
-    t0 = time.perf_counter()
     done = 0
     log = None
+    first_step_s, t_rest = 0.0, None
     while done < args.steps:
-        n = min(args.log_every, args.steps - done)
+        # step 1 alone (it compiles), then chunks of --log-every
+        n = 1 if done == 0 else min(args.log_every, args.steps - done)
+        t0 = time.perf_counter()
         # the log CONTINUES across fit calls: comm_mb / wall_s / grad
         # evals are cumulative, and schedule-entry comm accounting stays
-        # aligned round to round
+        # aligned round to round; float(loss) at the log point syncs
         state, log = trainer.fit(state, it, n, log_every=n, log=log)
+        dt = time.perf_counter() - t0
+        if done == 0:
+            first_step_s = dt
+        else:
+            t_rest = (t_rest or 0.0) + dt
         done += n
+        rate = (f"first step {dt:.1f} s incl. compile" if done == 1 else
+                f"{t_rest / (done - 1) * 1e3:.0f} ms/step after step 1")
         print(f"[train] step {done:5d} loss={log.loss[-1]:.4f} "
               f"consensus={log.consensus[-1]:.3e} "
               f"comm={log.comm_mb[-1]:.1f}MB "
-              f"evals={log.grad_evals[-1]} "
-              f"({(time.perf_counter() - t0) / done * 1e3:.0f} ms/step)")
+              f"evals={log.grad_evals[-1]} ({rate})")
         if args.ckpt and args.ckpt_every and done % args.ckpt_every == 0:
             save(args.ckpt, state, step=done,
                  meta={"arch": args.arch, "optimizer": args.optimizer})
@@ -244,6 +309,11 @@ def main() -> None:
         save(args.ckpt, state, step=done,
              meta={"arch": args.arch, "optimizer": args.optimizer})
         print(f"[train] final checkpoint -> {args.ckpt}")
+    return TrainRun(args=args, cfg=cfg, cuts=cuts, trainer=trainer,
+                    state=state, log=log, n_params=n_params,
+                    first_step_s=first_step_s,
+                    steady_ms=(t_rest / (done - 1) * 1e3
+                               if t_rest is not None else None))
 
 
 if __name__ == "__main__":

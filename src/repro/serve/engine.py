@@ -342,13 +342,14 @@ class DecodeEngine:
         self.last_version = version
         return jnp.stack(out, axis=1)
 
-    def generate(self, prompts: Sequence[jax.Array], n_new: int
-                 ) -> List[jax.Array]:
+    def generate(self, prompts: Sequence[jax.Array], n_new: int, *,
+                 extras: Optional[dict] = None) -> List[jax.Array]:
         """Serve a ragged request list: group by prompt length, pad each
         group to its bucket (batch rows replicate the first request; pad
         rows are dropped on the way out), split groups larger than the
-        biggest bucket. Returns one (n_new,) int32 array per request, in
-        request order."""
+        biggest bucket. ``extras`` holds per-request prefill inputs
+        (``patches`` / ``audio_embeds``), one row per prompt. Returns one
+        (n_new,) int32 array per request, in request order."""
         prompts = [jnp.asarray(p) for p in prompts]
         if any(p.ndim != 1 for p in prompts):
             raise ValueError("generate() takes 1-D token prompts; use "
@@ -364,11 +365,13 @@ class DecodeEngine:
                                      pad_seq=self.pad_seq)
                 take = pending[:B]
                 pending = pending[B:]
-                rows = [jnp.pad(prompts[i], (0, S - L)) for i in take]
-                while len(rows) < B:          # batch-dim padding
-                    rows.append(rows[0])
+                # batch-dim padding replicates the group's first request
+                idx = take + [take[0]] * (B - len(take))
+                rows = [jnp.pad(prompts[i], (0, S - L)) for i in idx]
                 out = self.generate_batch(
-                    jnp.stack(rows).astype(jnp.int32), n_new, true_len=L)
+                    jnp.stack(rows).astype(jnp.int32), n_new, true_len=L,
+                    extras=None if extras is None else {
+                        k: v[jnp.asarray(idx)] for k, v in extras.items()})
                 for r, i in enumerate(take):
                     results[i] = out[r]
         return results  # type: ignore[return-value]

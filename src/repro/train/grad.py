@@ -123,7 +123,7 @@ def psum_replicated(x: jax.Array, axis_name: str) -> jax.Array:
     invariant of a sharded loss, whose final scalar is identical on every
     shard of the model group.
 
-    Under ``shard_map(check_rep=False)`` replication is untracked, so the
+    Under ``shard_map(check_vma=False)`` replication is untracked, so the
     transpose of a plain ``lax.psum`` is another psum: with the replicated
     cotangent of a loss that silently multiplies every gradient by the
     model-group size M. This wrapper's backward pass is the identity

@@ -121,6 +121,11 @@ class DecentralizedTrainer:
         sits at ``max_chunks``, ``lr_decay``/``lr_decay_every`` decay
         eta via ``opt.rebuild`` (one legitimate recompile per decay,
         like an elastic resize).
+      donate: hand the optimizer state's buffers to the jitted step
+        (``donate_argnums``), so its output reuses them instead of
+        holding a second copy of params and moments during the step —
+        what lets a full-width model fit one chip. The state passed to
+        ``fit`` must then not be used again; ``fit`` returns the live one.
 
     Example:
       >>> import jax.numpy as jnp
@@ -145,8 +150,10 @@ class DecentralizedTrainer:
                  opt: DecentralizedOptimizer, *, microbatch: int = 1,
                  sharded_loss: Optional[Callable] = None,
                  plan: Any = None, recompile_limit: Optional[int] = None,
-                 damping: "None | str | DampingConfig" = None):
+                 damping: "None | str | DampingConfig" = None,
+                 donate: bool = False):
         self.loss_fn = loss_fn
+        self._donate = donate
         self._microbatch = microbatch
         self._sharded_loss = sharded_loss
         self._plan = plan
@@ -194,7 +201,8 @@ class DecentralizedTrainer:
                 losses, grads = self.pipeline.value_and_grad(state, batch)
                 return self.opt.step(state, grads), jnp.mean(losses)
 
-        self._step = jax.jit(step)
+        self._step = jax.jit(step, donate_argnums=(0,) if self._donate
+                             else ())
         if self._recompile_limit is not None:
             # JXL003 gate: every fit() call's abstract signature is hashed;
             # exceeding the limit raises. Built fresh here so an elastic
@@ -204,6 +212,15 @@ class DecentralizedTrainer:
             from repro.analysis.jaxpr_lint import RecompileWatch
             self.recompile_watch = RecompileWatch(
                 "trainer.step", limit=self._recompile_limit)
+
+    def lower_step(self, state: Any, batch: PyTree) -> Any:
+        """``jax.jit(step).lower`` for one (state, batch): the program
+        ``fit`` runs, for inspection (``.compile().as_text()``, memory
+        analysis). Nothing executes and ``state`` is not consumed."""
+        batch = self._place_batch(batch)
+        if self._damping is not None:
+            return self._step.lower(state, self.damp_state, batch)
+        return self._step.lower(state, batch)
 
     def init(self, params: PyTree) -> Any:
         stacked = stack_params(params, self.opt.K)
@@ -316,7 +333,7 @@ class DecentralizedTrainer:
                     self.recompile_watch.observe(state, batch)
                     self.recompile_watch.check()
                 state, loss = self._step(state, batch)
-            if (t + 1) % self.opt.cfg.period == 0:
+            if (step0 + t + 1) % self.opt.cfg.period == 0:
                 comm_mb += self._round_mb(state, comm_rounds)
                 comm_rounds += 1
             if hook is not None and hook_every > 0 \

@@ -41,7 +41,8 @@ def needs_devices(n):
 def worker_mesh():
     if jax.device_count() < K:
         pytest.skip(f"needs >= {K} devices")
-    return jax.make_mesh((K,), ("worker",))
+    from repro.launch.mesh import make_worker_mesh
+    return make_worker_mesh(K)
 
 
 # ------------------------------ validation ----------------------------------

@@ -77,7 +77,6 @@ _SCRIPT = textwrap.dedent("""
     print("OK sharded_step")
 
     # ---- 2. axis gossip (ppermute in shard_map) == stacked roll ----------
-    from jax.experimental.shard_map import shard_map
     topo = make_topology("ring", 4)
     x = jax.random.normal(jax.random.PRNGKey(2), (4, 16))
     want = gossip_roll({"x": x}, topo)["x"]
@@ -85,9 +84,9 @@ _SCRIPT = textwrap.dedent("""
     def gossip_fn(xs):
         return gossip_axis({"x": xs}, topo, "data")["x"]
 
-    got = shard_map(gossip_fn, mesh=mesh,
-                    in_specs=P("data", None),
-                    out_specs=P("data", None))(x)
+    got = jax.shard_map(gossip_fn, mesh=mesh,
+                        in_specs=P("data", None),
+                        out_specs=P("data", None))(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
     print("OK axis_gossip")

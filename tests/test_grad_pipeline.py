@@ -392,6 +392,9 @@ class TestNoFullParamAllGather:
         # any size; gossip permutes bounded by one device's packed block;
         # the activation psums bounded by B×DOUT f32 (+ slack for bias
         # assembly and CD-Adam per-leaf scales), far below parameter size.
+        # XLA's all-reduce combiner merges the activation psum with the
+        # bias assembly into one variadic all-reduce; the bound holds for
+        # each of its operands.
         spec = InvariantSpec(
             name=f"sharded2d/{kind}/K{k}xM{m}",
             collective_counts={"all-gather": 0, "all-to-all": 0,

@@ -89,6 +89,24 @@ ENTRY %main (p0: f32[128,8]) -> f32[128,8] {
                                  "reduce-scatter"))
 
 
+def test_variadic_collective_max_is_per_operand():
+    """A combined (variadic) all-reduce adds every operand to the kind's
+    bytes, but its largest single collective is its largest operand."""
+    hlo = """
+HloModule test
+
+ENTRY %main (p0: f32[8,64], p1: f32[64]) -> (f32[8,64], f32[64]) {
+  %p0 = f32[8,64]{1,0} parameter(0)
+  %p1 = f32[64]{0} parameter(1)
+  ROOT %ar = (f32[8,64]{1,0}, f32[64]{0}) all-reduce(%p0, %p1), replica_groups={{0,1}}, to_apply=%add
+}
+"""
+    cost = analyze(hlo)
+    assert cost.coll["all-reduce"] == (8 * 64 + 64) * 4
+    assert cost.coll_counts["all-reduce"] == 1
+    assert cost.coll_max["all-reduce"] == 8 * 64 * 4
+
+
 def test_collectives_inside_while_multiplied():
     hlo = """
 HloModule test

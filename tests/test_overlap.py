@@ -223,10 +223,11 @@ ZOO = [("ring", 8), ("torus", 8), ("exponential", 8),
 @pytest.mark.parametrize("name,zk", ZOO)
 @pytest.mark.parametrize("weight_decay", [0.0, 1e-4])
 def test_gossip_adam_mix_bitwise_two_pass(name, zk, weight_decay):
-    """The single-VMEM-pass kernel must be bit-for-bit fused_adam
-    followed by gossip_mix: the neighbor half-steps it recomputes
-    in-VMEM round through the param dtype exactly like the two-pass
-    composition's HBM round-trip."""
+    """The single-VMEM-pass kernel must match fused_adam followed by
+    gossip_mix: m and v bit for bit, and the mixed params within the
+    few-ulp bound of :func:`_assert_mix_close` (the neighbor half-steps
+    it recomputes in-VMEM round through the param dtype like the
+    two-pass composition's HBM round-trip)."""
     from repro.core.topology import make_topology
     from repro.kernels import ops
 
@@ -246,14 +247,35 @@ def test_gossip_adam_mix_bitwise_two_pass(name, zk, weight_decay):
     got_p, got_m, got_v = ops.gossip_adam_mix(
         p, g, m, v, topo.offsets, topo.offset_weights, topo.self_weight,
         block_rows=rows, **kw)
-    assert bool((got_p == want).all())
+    _assert_mix_close(got_p, want, p2, topo)
     assert bool((got_m == m2).all())
     assert bool((got_v == v2).all())
 
 
+def _assert_mix_close(got_p, want, p2, topo):
+    """Fused vs two-pass params: |got - want| <= 2 eps * sum_j |w_j p2_j|.
+
+    The fused kernel recomputes every half-step in a different compiled
+    body than fused_adam, and the compiler may contract the Adam update's
+    multiply-adds differently there. Each half-step p2_j then differs by
+    at most one rounding (<= eps |p2_j|), which the mix weights by w_j;
+    the factor 2 covers the mix's own final rounding. Elements of m and v
+    never pass through the mix and stay bit for bit."""
+    from repro.kernels import ops
+
+    mag = ops.gossip_mix(jnp.abs(p2), topo.offsets,
+                         [abs(w) for w in topo.offset_weights],
+                         abs(topo.self_weight), block_rows=p2.shape[1])
+    eps = float(jnp.finfo(jnp.float32).eps)
+    err = np.abs(np.asarray(got_p) - np.asarray(want))
+    bound = 2 * eps * np.asarray(mag)
+    assert (err <= bound).all(), float((err / bound).max())
+
+
 def test_gossip_adam_mix_bf16_moments_tau0():
     """bf16 moment buffers + the tau=0 rsqrt step variant round-trip the
-    kernel's internal f32 math exactly like the two-pass path."""
+    kernel's internal f32 math like the two-pass path: m and v bit for
+    bit, params within the bound of :func:`_assert_mix_close`."""
     from repro.core.topology import make_topology
     from repro.kernels import ops
 
@@ -274,7 +296,7 @@ def test_gossip_adam_mix_bf16_moments_tau0():
         p, g, m, v, topo.offsets, topo.offset_weights, topo.self_weight,
         block_rows=rows, **kw)
     assert got_m.dtype == jnp.bfloat16 and got_v.dtype == jnp.bfloat16
-    assert bool((got_p == want).all())
+    _assert_mix_close(got_p, want, p2, topo)
     assert bool((got_m == m2).all())
     assert bool((got_v == v2).all())
 
@@ -435,3 +457,47 @@ def test_env_setup_platform_setdefault():
     e2 = {"REPRO_ASYNC_COLLECTIVES": "0"}
     lenv.setup(2, platform="cpu", env=e2)
     assert e2["JAX_PLATFORMS"] == "cpu"
+
+
+def test_env_compile_cache_rule():
+    """A preset JAX_COMPILATION_CACHE_DIR is left alone; otherwise the
+    cache goes to the fixed <checkout>/.jax_cache."""
+    import pathlib
+
+    from repro.launch import env as lenv
+    e = {"JAX_COMPILATION_CACHE_DIR": "/elsewhere",
+         "REPRO_ASYNC_COLLECTIVES": "0"}
+    lenv.setup(2, env=e)
+    assert e["JAX_COMPILATION_CACHE_DIR"] == "/elsewhere"
+    e2 = {"REPRO_ASYNC_COLLECTIVES": "0"}
+    lenv.setup(2, env=e2)
+    cache = pathlib.Path(e2["JAX_COMPILATION_CACHE_DIR"])
+    assert cache == pathlib.Path(lenv.DEFAULT_CACHE_DIR)
+    assert cache.name == ".jax_cache"
+    assert (cache.parent / "src" / "repro" / "launch" / "env.py").is_file()
+
+
+def test_env_compile_cache_entries_land_in_set_dir(tmp_path):
+    """End to end in a fresh process: setup() keeps the exported cache
+    directory, and a compile lands there."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "from repro.launch import env; env.setup(1)\n"
+        "import jax, jax.numpy as jnp\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.jit(lambda x: jnp.sin(x) * 3)(jnp.ones(8)).block_until_ready()\n"
+        "print(jax.config.jax_compilation_cache_dir)\n")
+    child_env = dict(os.environ, JAX_PLATFORMS="cpu",
+                     JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+                     PYTHONPATH=os.pathsep.join(
+                         [os.path.join(os.path.dirname(__file__), "..",
+                                       "src")]
+                         + [p for p in [os.environ.get("PYTHONPATH")] if p]))
+    out = subprocess.run([sys.executable, "-c", code], env=child_env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == str(tmp_path)
+    assert any(tmp_path.iterdir())

@@ -127,7 +127,8 @@ class TestPublishParity:
     @pytest.mark.skipif(jax.device_count() < K,
                         reason=f"needs >= {K} devices (tier1.sh forces 8)")
     def test_parity_under_worker_mesh(self):
-        mesh = jax.make_mesh((K,), ("worker",))
+        from repro.launch.mesh import make_worker_mesh
+        mesh = make_worker_mesh(K)
         opt = make_optimizer("d-adam", K=K, eta=1e-2, period=2,
                              backend="pallas", comm="axis", mesh=mesh)
         state = opt.init(ragged_tree(KEY, K))
@@ -143,7 +144,8 @@ class TestPublishParity:
     @pytest.mark.skipif(jax.device_count() < 4,
                         reason="needs >= 4 devices (tier1.sh forces 8)")
     def test_parity_under_2d_mesh(self):
-        mesh = jax.make_mesh((2, 2), ("worker", "model"))
+        from repro.launch.mesh import make_worker_mesh
+        mesh = make_worker_mesh(2, model_parallel=2)
         opt = make_optimizer("d-adam", K=2, eta=1e-2, period=2,
                              backend="pallas", comm="axis", mesh=mesh)
         state = opt.init(ragged_tree(KEY, 2))

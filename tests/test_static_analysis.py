@@ -141,12 +141,11 @@ class TestTopologyInvariants:
 
 class TestJaxprLint:
     def _shard_mapped(self, body):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         import numpy as np
         mesh = Mesh(np.array(jax.devices()[:1]), ("worker",))
-        return shard_map(body, mesh=mesh, in_specs=P(),
-                         out_specs=P(), check_rep=False)
+        return jax.shard_map(body, mesh=mesh, in_specs=P(),
+                             out_specs=P(), check_vma=False)
 
     def test_raw_psum_flagged(self):
         fn = self._shard_mapped(lambda x: jax.lax.psum(x, "worker"))
