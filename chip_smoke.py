@@ -125,8 +125,8 @@ def train(tag: str, argv: list, *, kernels: bool = True, inspect=None,
     # the program fit ran (a persistent-cache hit when the cache is on)
     compiled = run.trainer.lower_step(run.state, batch).compile()
     info = dict(hlo_counts(compiled), cuts=run.cuts, n_params=run.n_params,
-                losses=losses, first_step_s=run.first_step_s,
-                steady_ms=run.steady_ms, cfg=run.cfg,
+                losses=losses, wall_s=list(run.log.wall_s),
+                steps=list(run.log.step), cfg=run.cfg,
                 state_gb=sum(x.nbytes for x in
                              jax.tree_util.tree_leaves(run.state)) / 1e9,
                 temp_gb=compiled.memory_analysis().temp_size_in_bytes / 1e9)
@@ -143,12 +143,13 @@ def train(tag: str, argv: list, *, kernels: bool = True, inspect=None,
         run.trainer.opt.params_of(run.state))
     del run
     gc.collect()
-    steady = (f"{info['steady_ms']:.1f} ms/step after step 1"
-              if info["steady_ms"] is not None else "one step")
+    wall, steps = info["wall_s"], info["steps"]
+    steady = (f"{(wall[-1] - wall[0]) / (steps[-1] - 1) * 1e3:.1f} ms/step "
+              f"after step 1" if steps[-1] > 1 else "one step")
     print(f"[{tag}] cuts: {', '.join(info['cuts']) or 'none'}; params "
           f"{info['n_params'] / 1e6:.1f}M per worker; resident state "
           f"{info['state_gb']:.2f} GB; step temp {info['temp_gb']:.2f} GB; "
-          f"first step {info['first_step_s']:.1f} s incl. compile; {steady}; "
+          f"first step {wall[0]:.1f} s incl. compile; {steady}; "
           f"tpu_custom_call x{info['kernels']}; collective-permute "
           f"x{info['collective_permutes']}; losses "
           f"{[round(x, 5) for x in losses]}; run "

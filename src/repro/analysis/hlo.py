@@ -58,6 +58,7 @@ _COMP_HDR_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
 _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s([\w\-]+)\((.*)$")
 _REF_RE = re.compile(r"%([\w.\-]+)")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?\bop_name="([^"]*)"')
 _CONST_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*[su]\d+\[\]\s+constant\((\d+)\)")
 _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
@@ -185,6 +186,53 @@ def parse_hlo(text: str) -> Tuple[Dict[str, Computation], Optional[str]]:
         cur.instrs.append(ins)
         cur.by_name[name] = ins
     return comps, entry
+
+
+def op_scopes(text: str) -> Dict[str, str]:
+    """``{instruction: op_name}`` over a module's HLO text: the
+    ``metadata={op_name=...}`` each instruction was traced under, i.e. its
+    ``jax.named_scope`` path (``jit(step)/grad/jvp(model)/dot_general``).
+
+    An instruction without one (a layout copy or bitcast the compiler
+    inserted, a parameter) takes the op_name of its nearest user that has
+    one, first in instruction order, within its computation; failing that,
+    the op_name of the instruction that calls its computation (the
+    ``conditional`` of a branch, the ``while`` of a body). ``""`` where
+    neither gives one."""
+    comps, _ = parse_hlo(text)
+    out: Dict[str, str] = {}
+    comp_of: Dict[str, str] = {}
+    caller: Dict[str, str] = {}
+    for comp in comps.values():
+        users: Dict[str, List[str]] = {}
+        own: Dict[str, str] = {}
+        for ins in comp.instrs:
+            m = _OP_NAME_RE.search(ins.attrs)
+            own[ins.name] = m.group(1) if m else ""
+            comp_of[ins.name] = comp.name
+            for o in dict.fromkeys(ins.operands):
+                users.setdefault(o, []).append(ins.name)
+            for ref in _REF_RE.findall(ins.attrs):
+                if ref in comps:
+                    caller.setdefault(ref, ins.name)
+        for ins in comp.instrs:
+            name, seen, level = own[ins.name], {ins.name}, [ins.name]
+            while not name and level:
+                nxt = []
+                for f in level:
+                    for u in users.get(f, ()):
+                        if u not in seen:
+                            seen.add(u)
+                            nxt.append(u)
+                level = nxt
+                name = next((own[u] for u in level if own[u]), "")
+            out[ins.name] = name
+    for name in out:
+        n = name
+        while not out[name] and comp_of[n] in caller:
+            n = caller[comp_of[n]]
+            out[name] = out[n]
+    return out
 
 
 def _trip_count(comps: Dict[str, Computation], cond_name: str

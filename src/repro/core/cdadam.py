@@ -33,6 +33,7 @@ from repro.core.topology import Topology
 # light import only — the Pallas kernel stack (repro.kernels.ops) loads
 # lazily inside the pallas-only paths
 from repro.kernels import pack as packing
+from repro.tracing import scoped
 
 PyTree = Any
 
@@ -163,6 +164,7 @@ class PackedCDAdamState:
 # --------------------- stacked encode/decode helpers -----------------------
 
 
+@scoped("compress")
 def _encode_stacked(comp: Compressor, tree: PyTree) -> PyTree:
     """vmap Q.encode over the leading worker dim of every leaf (per-worker
     scales!), producing payload leaves that keep the leading K dim.
@@ -177,6 +179,7 @@ def _encode_stacked(comp: Compressor, tree: PyTree) -> PyTree:
     )
 
 
+@scoped("compress")
 def _decode_stacked(comp: Compressor, payload: PyTree, like: PyTree) -> PyTree:
     def dec(p, x):
         return jax.vmap(lambda q: comp.decode(q, x.shape[1:], x.dtype))(p)
@@ -352,6 +355,7 @@ def _mix_with_hats(x_half: PyTree, hat_self: PyTree,
     return jax.tree_util.tree_map(mixed, x_half, hat_self, *hat_nbrs)
 
 
+@scoped("gossip")
 def _comm_round(state_half: CDAdamState, topo: Topology, cfg: CDAdamConfig,
                 comp: Compressor, r: jax.Array) -> CDAdamState:
     """Lines 8-11 of Alg. 2 on the half-step parameters."""
@@ -391,6 +395,7 @@ def _comm_round(state_half: CDAdamState, topo: Topology, cfg: CDAdamConfig,
                        None if pending is None else tuple(new_pending))
 
 
+@scoped("gossip")
 def _comm_round_pallas(state_half: CDAdamState, topo: Topology,
                        cfg: CDAdamConfig) -> CDAdamState:
     """Lines 8-11 of Alg. 2 with the sign compressor fused into Pallas
@@ -440,6 +445,7 @@ def _comm_round_pallas(state_half: CDAdamState, topo: Topology,
     return CDAdamState(x_new, mom, new_hat_self, tuple(new_hat_nbrs))
 
 
+@scoped("gossip")
 def _comm_round_packed(state_half: PackedCDAdamState, topo: Topology,
                        cfg: CDAdamConfig, r: jax.Array) -> PackedCDAdamState:
     """Lines 8-11 of Alg. 2 entirely on resident packed buffers.

@@ -47,6 +47,7 @@ from repro.core.topology import GridShift, Topology, offset_perm
 # paths so backend='reference' users never pay for it
 from repro.kernels import pack as packing
 from repro.kernels.pack import BLOCK_ROWS
+from repro.tracing import scoped
 
 PyTree = Any
 
@@ -225,6 +226,7 @@ def _local_update_pallas(
             packing.unpack(vo, spec_m))
 
 
+@scoped("adam")
 def local_update(
     params: PyTree, grads: PyTree, mom: AdamMoments, cfg: DAdamConfig
 ) -> Tuple[PyTree, AdamMoments]:
@@ -636,6 +638,7 @@ def gossip_packed_overlap(buf: jax.Array, stale: StaleBufs, topo: Topology,
 # --------------------- round dispatch (schedule-aware) ----------------------
 
 
+@scoped("gossip")
 def _gossip_round(params: PyTree, stale: Optional[StaleBufs],
                   topo: "Topology | TopologySchedule", cfg: DAdamConfig,
                   r: jax.Array) -> Tuple[PyTree, Optional[StaleBufs]]:
@@ -666,6 +669,7 @@ def _gossip_round(params: PyTree, stale: Optional[StaleBufs],
     return once((params, stale), topo)
 
 
+@scoped("gossip")
 def _gossip_packed_round(buf: jax.Array, stale: Optional[StaleBufs],
                          topo: "Topology | TopologySchedule",
                          cfg: DAdamConfig, r: jax.Array
@@ -819,6 +823,7 @@ def init(params_stacked: PyTree, cfg: DAdamConfig,
     return state
 
 
+@scoped("adam")
 def _fused_local_packed(state: PackedDAdamState, grads: Any,
                         cfg: DAdamConfig
                         ) -> Tuple[jax.Array, jax.Array, jax.Array,
@@ -875,12 +880,14 @@ def _step_packed_fused(state: PackedDAdamState, grads: Any,
     kw = dict(eta=cfg.eta, beta1=cfg.beta1, beta2=cfg.beta2, tau=cfg.tau,
               weight_decay=cfg.weight_decay)
 
+    @scoped("gossip")
     def fused(op):
         p, m, v = op
         return ops.gossip_adam_mix(p, gbuf, m, v, topo.offsets,
                                    topo.offset_weights, topo.self_weight,
                                    **kw)
 
+    @scoped("adam")
     def plain(op):
         p, m, v = op
         return ops.fused_adam(p, gbuf, m, v, **kw)

@@ -17,6 +17,7 @@ from repro.kernels import fused_adam as _adam
 from repro.kernels import gossip as _gossip
 from repro.kernels import rwkv_scan as _wkv
 from repro.kernels import sign_compress as _sc
+from repro.tracing import scoped
 
 
 def _interpret(override: Optional[bool]) -> bool:
@@ -36,6 +37,7 @@ def sign_compress(x, hat, *, interpret: Optional[bool] = None):
     return _sc.sign_compress(x, hat, interpret=_interpret(interpret))
 
 
+@scoped("compress")
 def sign_compress_stacked(x, hat, *, n_true: Optional[int] = None,
                           reduce_axis: Optional[str] = None,
                           interpret: Optional[bool] = None):

@@ -57,6 +57,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.tracing import scoped
+
 PyTree = Any
 
 LANE = 128
@@ -205,6 +207,7 @@ def local_chunk_elems(spec: PackSpec) -> Tuple[int, ...]:
     return _shard_chunks(spec)
 
 
+@scoped("unpack")
 def unpack_local(buf: jax.Array, spec: PackSpec) -> PyTree:
     """Per-leaf *local slices* of one row-shard block of a (row-sharded)
     packed buffer — the model-parallel counterpart of :func:`unpack`.
@@ -283,6 +286,7 @@ def _segment_pads(spec: PackSpec) -> Tuple[int, ...]:
                  for c, sz in zip(_shard_chunks(spec), spec.sizes))
 
 
+@scoped("pack")
 def pack(tree: PyTree, spec: PackSpec, dtype: Any = None) -> jax.Array:
     """Flatten ``tree`` into a (rows, LANE) — or (K, rows, LANE) — buffer.
 
@@ -346,6 +350,7 @@ def _unpack_one_row(row: jax.Array, spec: PackSpec) -> PyTree:
     return jax.tree_util.tree_unflatten(spec.treedef, leaves)
 
 
+@scoped("unpack")
 def unpack_worker(buf: jax.Array, spec: PackSpec, k: int) -> PyTree:
     """Worker ``k``'s param pytree straight from the stacked buffer.
 
@@ -369,6 +374,7 @@ def unpack_worker(buf: jax.Array, spec: PackSpec, k: int) -> PyTree:
     return _unpack_one_row(buf[k], spec)
 
 
+@scoped("unpack")
 def unpack_mean(buf: jax.Array, spec: PackSpec) -> PyTree:
     """The consensus-mean param pytree straight from the stacked buffer.
 
@@ -405,6 +411,7 @@ def _leaf_rows(buf: jax.Array, spec: PackSpec, shapes) -> list:
     return out
 
 
+@scoped("unpack")
 def unpack(buf: jax.Array, spec: PackSpec) -> PyTree:
     """Exact inverse of ``pack``: strip padding, split, restore per-leaf
     shape and dtype."""

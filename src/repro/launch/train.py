@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import time
 from typing import Any, Optional, Sequence, Tuple
 
 if __name__ == "__main__":
@@ -69,9 +68,9 @@ class TrainRun:
     """What one ``main(argv)`` run leaves behind, for in-process callers.
 
     ``state`` is the live optimizer state (the trainer donates the one
-    it was given each step); ``first_step_s`` is the wall time of step 1,
-    compilation included, and ``steady_ms`` the mean of the later
-    steps."""
+    it was given each step). ``log.wall_s[0]`` is the wall time of step
+    1, compilation included; the run logs step 1 alone, then every
+    ``--log-every`` steps."""
     args: argparse.Namespace
     cfg: Any
     cuts: Tuple[str, ...]
@@ -79,8 +78,6 @@ class TrainRun:
     state: Any
     log: Any
     n_params: int
-    first_step_s: float
-    steady_ms: Optional[float]
 
 
 def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
@@ -280,23 +277,18 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
     it = make_batch_iter(cfg, args.workers, args.batch, args.seq, args.skew)
     done = 0
     log = None
-    first_step_s, t_rest = 0.0, None
     while done < args.steps:
         # step 1 alone (it compiles), then chunks of --log-every
         n = 1 if done == 0 else min(args.log_every, args.steps - done)
-        t0 = time.perf_counter()
         # the log CONTINUES across fit calls: comm_mb / wall_s / grad
         # evals are cumulative, and schedule-entry comm accounting stays
         # aligned round to round; float(loss) at the log point syncs
         state, log = trainer.fit(state, it, n, log_every=n, log=log)
-        dt = time.perf_counter() - t0
-        if done == 0:
-            first_step_s = dt
-        else:
-            t_rest = (t_rest or 0.0) + dt
         done += n
-        rate = (f"first step {dt:.1f} s incl. compile" if done == 1 else
-                f"{t_rest / (done - 1) * 1e3:.0f} ms/step after step 1")
+        wall = log.wall_s
+        rate = (f"first step {wall[0]:.1f} s incl. compile" if done == 1
+                else f"{(wall[-1] - wall[0]) / (done - 1) * 1e3:.0f} "
+                     f"ms/step after step 1")
         print(f"[train] step {done:5d} loss={log.loss[-1]:.4f} "
               f"consensus={log.consensus[-1]:.3e} "
               f"comm={log.comm_mb[-1]:.1f}MB "
@@ -310,10 +302,7 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
              meta={"arch": args.arch, "optimizer": args.optimizer})
         print(f"[train] final checkpoint -> {args.ckpt}")
     return TrainRun(args=args, cfg=cfg, cuts=cuts, trainer=trainer,
-                    state=state, log=log, n_params=n_params,
-                    first_step_s=first_step_s,
-                    steady_ms=(t_rest / (done - 1) * 1e3
-                               if t_rest is not None else None))
+                    state=state, log=log, n_params=n_params)
 
 
 if __name__ == "__main__":

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import pack as packing
+from repro.tracing import scoped
 
 PyTree = Any
 
@@ -337,6 +338,11 @@ def make_grad_pipeline(loss: Callable[[PyTree, PyTree], jax.Array],
     cfg = opt.cfg
     packed = getattr(cfg, "backend", "reference") == "pallas"
     M = int(getattr(cfg, "model_parallel", 1))
+    # every mode below differentiates these, so their ops carry the
+    # 'model' scope forward and (as transpose(jvp(model))) backward
+    loss = scoped("model")(loss)
+    if sharded_loss is not None:
+        sharded_loss = scoped("model")(sharded_loss)
     if microbatch < 1:
         raise ValueError(f"microbatch must be >= 1, got {microbatch}")
     if damping_chunks:

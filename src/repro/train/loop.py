@@ -188,18 +188,27 @@ class DecentralizedTrainer:
 
             def step(state, dstate, batch):
                 n = damping_mod.chunks_of(dstate, dcfg, self.opt.K)
-                losses, grads = self.pipeline.value_and_grad(
-                    state, batch, n)
-                new_state = self.opt.step(state, grads)
+                with jax.named_scope("grad"):
+                    losses, grads = self.pipeline.value_and_grad(
+                        state, batch, n)
+                    loss = jnp.mean(losses)
+                with jax.named_scope("opt"):
+                    new_state = self.opt.step(state, grads)
                 # the damping signal updates OUTSIDE the comm shard_maps,
                 # from the global (K,) per-worker losses — stacked and
                 # axis comm modes see the identical EMA
-                new_dstate = damping_mod.update(dstate, losses, dcfg)
-                return new_state, new_dstate, jnp.mean(losses)
+                with jax.named_scope("damping"):
+                    new_dstate = damping_mod.update(dstate, losses, dcfg)
+                return new_state, new_dstate, loss
         else:
             def step(state, batch):
-                losses, grads = self.pipeline.value_and_grad(state, batch)
-                return self.opt.step(state, grads), jnp.mean(losses)
+                with jax.named_scope("grad"):
+                    losses, grads = self.pipeline.value_and_grad(state,
+                                                                 batch)
+                    loss = jnp.mean(losses)
+                with jax.named_scope("opt"):
+                    new_state = self.opt.step(state, grads)
+                return new_state, loss
 
         self._step = jax.jit(step, donate_argnums=(0,) if self._donate
                              else ())
@@ -310,7 +319,12 @@ class DecentralizedTrainer:
         ``ParamStore`` publish here). The hook runs on the host between
         jitted steps: it must not mutate ``state``, and anything it
         launches (a ``device_put``, an unpack-once publish) is async, so
-        training does not stall on it."""
+        training does not stall on it.
+
+        Each iteration is a profiler step (``StepTraceAnnotation("train")``)
+        holding the host spans ``fit.batch``, ``fit.watch``,
+        ``fit.dispatch``, ``fit.hook`` and ``fit.log`` (see
+        ``repro.tracing``)."""
         log = log or TrainLog()
         comm_rounds = log.comm_rounds_total
         comm_mb = log.comm_mb_total
@@ -320,40 +334,44 @@ class DecentralizedTrainer:
         evals_per_step = self.opt.K * self.pipeline.microbatch
         t0 = time.perf_counter()
         for t in range(steps):
-            batch = self._place_batch(next(batch_iter))
-            if self._damping is not None:
+            with jax.profiler.StepTraceAnnotation("train",
+                                                  step_num=step0 + t + 1):
+                with jax.profiler.TraceAnnotation("fit.batch"):
+                    batch = self._place_batch(next(batch_iter))
+                damp = () if self._damping is None else (self.damp_state,)
                 if self.recompile_watch is not None:
-                    self.recompile_watch.observe(state, self.damp_state,
-                                                 batch)
-                    self.recompile_watch.check()
-                state, self.damp_state, loss = self._step(
-                    state, self.damp_state, batch)
-            else:
-                if self.recompile_watch is not None:
-                    self.recompile_watch.observe(state, batch)
-                    self.recompile_watch.check()
-                state, loss = self._step(state, batch)
-            if (step0 + t + 1) % self.opt.cfg.period == 0:
-                comm_mb += self._round_mb(state, comm_rounds)
-                comm_rounds += 1
-            if hook is not None and hook_every > 0 \
-                    and (t + 1) % hook_every == 0:
-                hook(step0 + t + 1, state)
-            if (t + 1) % log_every == 0 or t == steps - 1:
-                if self._damping is not None:
-                    evals = (log.grad_evals_total
-                             + int(self.damp_state.evals) - evals0_dev)
-                else:
-                    evals = log.grad_evals_total + (t + 1) * evals_per_step
-                log.step.append(step0 + t + 1)
-                log.loss.append(float(loss))
-                log.consensus.append(
-                    float(consensus_error(self.opt.params_of(state))))
-                log.comm_mb.append(comm_mb)
-                log.wall_s.append(log.wall_s_total
-                                  + time.perf_counter() - t0)
-                log.grad_evals.append(evals)
-                self._maybe_decay_lr()
+                    with jax.profiler.TraceAnnotation("fit.watch"):
+                        self.recompile_watch.observe(state, *damp, batch)
+                        self.recompile_watch.check()
+                with jax.profiler.TraceAnnotation("fit.dispatch"):
+                    state, *damp, loss = self._step(state, *damp, batch)
+                if damp:
+                    self.damp_state, = damp
+                if (step0 + t + 1) % self.opt.cfg.period == 0:
+                    comm_mb += self._round_mb(state, comm_rounds)
+                    comm_rounds += 1
+                if hook is not None and hook_every > 0 \
+                        and (t + 1) % hook_every == 0:
+                    with jax.profiler.TraceAnnotation("fit.hook"):
+                        hook(step0 + t + 1, state)
+                if (t + 1) % log_every == 0 or t == steps - 1:
+                    with jax.profiler.TraceAnnotation("fit.log"):
+                        if self._damping is not None:
+                            evals = (log.grad_evals_total
+                                     + int(self.damp_state.evals)
+                                     - evals0_dev)
+                        else:
+                            evals = (log.grad_evals_total
+                                     + (t + 1) * evals_per_step)
+                        log.step.append(step0 + t + 1)
+                        log.loss.append(float(loss))
+                        log.consensus.append(
+                            float(consensus_error(self.opt.params_of(state))))
+                        log.comm_mb.append(comm_mb)
+                        log.wall_s.append(log.wall_s_total
+                                          + time.perf_counter() - t0)
+                        log.grad_evals.append(evals)
+                        self._maybe_decay_lr()
         log.steps_total = step0 + steps
         log.comm_rounds_total = comm_rounds
         log.comm_mb_total = comm_mb
