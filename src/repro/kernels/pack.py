@@ -51,7 +51,9 @@ a registered pytree (how the packed optimizer states hold them).
 """
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Optional, Tuple
+import contextvars
+import functools
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -446,3 +448,167 @@ def unpack(buf: jax.Array, spec: PackSpec) -> PyTree:
                                         spec.dtypes, spec.shapes)
         ]
     return jax.tree_util.tree_unflatten(spec.treedef, leaves)
+
+
+# ---------------------- narrow leaves as lane-dense rows ---------------------
+#
+# A leaf whose rows are narrower than a lane, an embedding table (N, 10)
+# say, sits lane-dense in its packed segment, but its (N, E) view is
+# lane-padded to 128 on a TPU and relaid both ways around every use. Inside
+# :func:`worker_map`, :func:`take_rows` instead reads such a leaf's rows
+# straight from the worker's (rows, LANE) buffer and puts their gradient
+# into a block of the same shape. Only this module knows that layout.
+
+_ROW_BLOCKS: contextvars.ContextVar = contextvars.ContextVar(
+    "row_blocks", default=None)
+
+
+class _RowBlock(NamedTuple):
+    """Where a leaf lives in one worker's packed buffer: element
+    ``j`` of the flattened leaf is element ``start * LANE + j`` of the
+    flattened ``buf`` (``(rows, LANE)``), inside rows ``[start, stop)``."""
+    buf: jax.Array
+    start: int
+    stop: int
+
+
+def _row_block(leaf: Any) -> Optional[_RowBlock]:
+    """The :class:`_RowBlock` of ``leaf`` when it is a leaf handed to the
+    function :func:`worker_map` runs; ``None`` for any other array."""
+    entry = (_ROW_BLOCKS.get() or {}).get(id(leaf))
+    return entry[1] if entry is not None and entry[0] is leaf else None
+
+
+def worker_map(fn: Callable, buf: jax.Array, spec: PackSpec,
+               params: PyTree, *args) -> Any:
+    """``jax.vmap(fn)(params, *args)`` over the worker dim, where
+    ``params`` are the views :func:`unpack` gave of ``buf``, or those
+    views under sharding constraints. Inside ``fn``, :func:`take_rows` on
+    a leaf of a stacked, leaf-aligned buffer with one row shard reads the
+    worker's rows of ``buf`` (any other layout keeps ``table[ids]``), so
+    a view that no other op reads is never built."""
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    ranges = ()
+    if spec.stacked and spec.row_shards == 1 and spec.leaf_aligned:
+        ranges = leaf_row_ranges(spec)
+
+    def one(leaves, rows, *args):
+        token = _ROW_BLOCKS.set({id(leaf): (leaf, _RowBlock(rows, *r))
+                                 for leaf, r in zip(leaves, ranges)})
+        try:
+            return fn(jax.tree_util.tree_unflatten(treedef, leaves), *args)
+        finally:
+            _ROW_BLOCKS.reset(token)
+
+    return jax.vmap(one)(leaves, buf, *args)
+
+
+def take_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
+    """``table[ids]``: the rows of an ``(N, E)`` table (or the entries of
+    an ``(N,)`` one) at integer ``ids``, with ``table[ids]``'s handling of
+    negative and out-of-range ids.
+
+    Where ``table`` is a leaf :func:`worker_map` handed over unchanged and
+    its rows are narrower than a lane (``E < LANE``), the rows are read
+    from the buffer's own lane-dense ``(rows, LANE)`` tiles and their
+    gradient is scatter-added into tiles of the same shape. Anywhere else,
+    and for rows a lane wide or wider, it is exactly ``table[ids]``.
+    Values and gradients are those of ``table[ids]``; only the order in
+    which the cotangents of repeated ids are summed may differ."""
+    where = _row_block(table)
+    e = table.shape[1] if table.ndim == 2 else 1
+    if (where is None or table.ndim > 2 or e >= LANE
+            or where.buf.size >= 2 ** 31):
+        return table[ids]
+    n = table.shape[0]
+    with jax.named_scope("lookup"):
+        flat = ids.reshape(-1).astype(jnp.int32)
+        flat = jnp.where(flat < 0, flat + n, flat)
+        # as table[ids]: an out-of-range id reads the clamped row and its
+        # cotangent is dropped
+        valid = (flat >= 0) & (flat < n)
+        out = _lane_rows(where.buf, jnp.clip(flat, 0, n - 1), valid, e,
+                         where.start, where.stop)
+    return out.reshape(ids.shape + table.shape[1:]).astype(table.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _lane_rows(buf: jax.Array, ids: jax.Array, valid: jax.Array, e: int,
+               start: int, stop: int) -> jax.Array:
+    """``(len(ids), e)`` rows, ``e < LANE``, of the table whose flat
+    elements start at element ``start * LANE`` of ``buf`` (``(rows,
+    LANE)``) and lie in its rows ``[start, stop)``: row ``i`` is ``e``
+    elements at ``i * e`` from there, inside one buffer row when ``e``
+    divides LANE and inside two otherwise. Those rows are gathered whole,
+    and ``e`` lanes are selected out of them by a masked sum (one term is
+    nonzero, so it is exact)."""
+    win = 1 if LANE % e == 0 else 2
+    first = start * LANE + ids * e
+    r = jnp.minimum(first // LANE, stop - win)
+    off = first - r * LANE                       # in [0, win*LANE - e]
+    windows = jnp.concatenate([buf[r + i] for i in range(win)], axis=-1)
+    want = off[:, None, None] + jnp.arange(e)[None, :, None]
+    hit = jnp.arange(win * LANE)[None, None, :] == want
+    return jnp.sum(jnp.where(hit, windows[:, None, :], 0), axis=-1)
+
+
+def _lane_rows_fwd(buf, ids, valid, e, start, stop):
+    return (_lane_rows(buf, ids, valid, e, start, stop),
+            (ids, valid, buf.shape))
+
+
+def _lane_rows_bwd(e, start, stop, res, ct):
+    """The gradient lands in a block of the table's own rows, which the
+    buffer-shaped cotangent pads (XLA adds the pad into the sum of the
+    leaves' gradients)."""
+    ids, valid, shape = res
+    g = _sum_into_block((stop - start, shape[1]), ids * e, valid, ct)
+    return jnp.pad(g, ((start, shape[0] - stop), (0, 0))), None, None
+
+
+_lane_rows.defvjp(_lane_rows_fwd, _lane_rows_bwd)
+
+
+def _sum_into_block(shape, at: jax.Array, valid: jax.Array,
+                    ct: jax.Array) -> jax.Array:
+    """A zero ``shape`` block (``(rows, LANE)``) with each row of ``ct``
+    (``(m, e)``) added at flat offset ``at``, rows whose ``valid`` is
+    false dropped.
+
+    One scalar scatter-add does it. Rows of one element scatter as they
+    come. Wider rows are summed per offset first: the offsets are sorted,
+    each run of one offset is summed by a segmented scan, and the runs'
+    totals are moved to the front in offset order, so the scatter's
+    indices are sorted and unique. The TPU compiler takes minutes over a
+    scatter of that many scalars in any other order, and runs it
+    slower."""
+    m, e = ct.shape
+    zeros = jnp.zeros(shape, ct.dtype)
+    if e == 1:
+        row = jnp.where(valid, at // LANE, shape[0])
+        return zeros.at[row, at % LANE].add(ct[:, 0], mode="drop")
+    index = jnp.arange(m)
+    dropped = jnp.iinfo(jnp.int32).max
+    key, perm = jax.lax.sort((jnp.where(valid, at, dropped), index),
+                             num_keys=1)
+    new = key[1:] != key[:-1]
+    first = jnp.concatenate([jnp.ones((1,), bool), new])
+    last = jnp.concatenate([new, jnp.ones((1,), bool)]) & (key != dropped)
+    _, total = jax.lax.associative_scan(_segment_add, (first, ct[perm]))
+    slot, key = jax.lax.sort((jnp.where(last, index, m), key), num_keys=1)
+    ok = (slot < m)[:, None]
+    total = total[jnp.minimum(slot, m - 1)]
+    pos = jnp.where(ok, key[:, None], 0) + jnp.arange(e)
+    # slots past the last run get a row each past the block's end: the
+    # indices stay sorted and unique, and the scatter drops them
+    row = jnp.where(ok, pos // LANE, shape[0] + index[:, None])
+    return zeros.at[row, pos % LANE].add(
+        total, indices_are_sorted=True, unique_indices=True, mode="drop")
+
+
+def _segment_add(a, b):
+    """Segmented sum: ``(starts a segment, running sum)`` pairs."""
+    first_a, sum_a = a
+    first_b, sum_b = b
+    return first_a | first_b, jnp.where(first_b[:, None], sum_b,
+                                        sum_a + sum_b)

@@ -14,6 +14,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pack as packing
+
 PyTree = Any
 
 
@@ -30,6 +32,15 @@ def dense_init(key, d_in: int, d_out: int, dtype, scale: Optional[float] = None
 def embed_init(key, vocab: int, d: int, dtype) -> jax.Array:
     return (jax.random.normal(key, (vocab, d), jnp.float32) * 0.02
             ).astype(dtype)
+
+
+# ------------------------------- lookup ------------------------------------
+
+
+def lookup(table: jax.Array, ids: jax.Array) -> jax.Array:
+    """``table[ids]``, read from the packed buffer's own rows where the
+    table is a narrow leaf of it (``kernels.pack.take_rows``)."""
+    return packing.take_rows(table, ids)
 
 
 # ------------------------------- norms -------------------------------------

@@ -52,9 +52,10 @@ def deepfm_logits(params: PyTree, feat_ids: jax.Array,
                   dropout_key: Optional[jax.Array] = None,
                   dropout_rate: float = 0.5) -> jax.Array:
     """feat_ids: (B, n_fields) int32 — one active feature id per field."""
-    emb = params["embed"][feat_ids]                   # (B, F, E)
+    emb = common.lookup(params["embed"], feat_ids)    # (B, F, E)
     # first order
-    first = jnp.sum(params["linear"][feat_ids], axis=-1) + params["bias"]
+    first = (jnp.sum(common.lookup(params["linear"], feat_ids), axis=-1)
+             + params["bias"])
     # FM second order: 0.5 * ((sum e)^2 - sum e^2)
     s = jnp.sum(emb, axis=1)
     s2 = jnp.sum(emb * emb, axis=1)
@@ -93,8 +94,9 @@ def init_widedeep(key, n_features: int, n_fields: int, embed_dim: int = 10,
 def widedeep_logits(params: PyTree, feat_ids: jax.Array,
                     dropout_key: Optional[jax.Array] = None,
                     dropout_rate: float = 0.5) -> jax.Array:
-    emb = params["embed"][feat_ids]
-    wide = jnp.sum(params["linear"][feat_ids], axis=-1) + params["bias"]
+    emb = common.lookup(params["embed"], feat_ids)
+    wide = (jnp.sum(common.lookup(params["linear"], feat_ids), axis=-1)
+            + params["bias"])
     h = emb.reshape(emb.shape[0], -1)
     for i, layer in enumerate(params["mlp"]):
         h = h @ layer["w"] + layer["b"]

@@ -8,6 +8,8 @@ training step, in the one place that boundary is crossed:
 
     grad      train/loop.py   the grad pipeline's value_and_grad
     model     train/grad.py   the per-worker loss (and sharded_loss)
+    lookup    kernels/pack.py take_rows reading a narrow packed leaf's
+              rows straight from the buffer, inside model
     unpack    kernels/pack.py the packed buffer's per-leaf views
     pack      kernels/pack.py a pytree flattened into a packed buffer
     opt       train/loop.py   the optimizer step

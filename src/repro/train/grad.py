@@ -397,24 +397,32 @@ def _loss_constraints(plan: Any, tree: PyTree) -> PyTree:
     return loss_param_constraints(plan, tree)
 
 
+def _packed_one(loss, spec: packing.PackSpec, plan: Any):
+    """``(buf, batch) -> (losses (K,), grads buffer)``: the stacked
+    per-worker losses differentiated through ``packing.unpack`` w.r.t.
+    the resident buffer. The workers run under ``packing.worker_map``, so
+    a lookup into a narrow leaf reads the buffer's own rows."""
+
+    def one(buf, b):
+        def stacked_loss(bf):
+            params = packing.unpack(bf, spec)
+            if plan is not None:
+                params = _loss_constraints(plan, params)
+            losses = packing.worker_map(loss, bf, spec, params, b)
+            return jnp.sum(losses), losses
+
+        (_, losses), g = jax.value_and_grad(
+            stacked_loss, has_aux=True)(buf)
+        return losses, g
+
+    return one
+
+
 def _packed_vag(loss, opt, microbatch: int, plan: Any):
     """Differentiate-through-unpack, w.r.t. the resident buffer."""
 
     def vag(state, batch):
-        spec = state.spec
-
-        def one(buf, b):
-            def stacked_loss(bf):
-                params = packing.unpack(bf, spec)
-                if plan is not None:
-                    params = _loss_constraints(plan, params)
-                losses = jax.vmap(loss)(params, b)
-                return jnp.sum(losses), losses
-
-            (_, losses), g = jax.value_and_grad(
-                stacked_loss, has_aux=True)(buf)
-            return losses, g
-
+        one = _packed_one(loss, state.spec, plan)
         if microbatch <= 1:
             return one(state.buf, batch)
         micro = _split_micro(batch, microbatch, batch_dim=1)
@@ -474,20 +482,7 @@ def _packed_damped_vag(loss, opt, C: int, plan: Any):
     ``i < n (K,)`` zeroes whole workers' chunk contributions."""
 
     def vag(state, batch, n):
-        spec = state.spec
-
-        def one(buf, b):
-            def stacked_loss(bf):
-                params = packing.unpack(bf, spec)
-                if plan is not None:
-                    params = _loss_constraints(plan, params)
-                losses = jax.vmap(loss)(params, b)
-                return jnp.sum(losses), losses
-
-            (_, losses), g = jax.value_and_grad(
-                stacked_loss, has_aux=True)(buf)
-            return losses, g
-
+        one = _packed_one(loss, state.spec, plan)
         micro = _split_micro(batch, C, batch_dim=1)
         K = state.buf.shape[0]
 

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import make_optimizer
 from repro.core.topology import make_topology
 from repro.kernels import ops
+from repro.models.deepfm import deepfm_loss, init_deepfm
+from repro.train import DecentralizedTrainer
 
 # llama3.2-1b per-worker leaves as packed (rows, 128) segments
 MLP_ROWS = 2048 * 8192 // 128        # one swiglu projection: 131072 rows
@@ -144,3 +147,37 @@ def test_packed_step_compiles(one_chip, kind, monkeypatch):
     text = _compiled_text(opt.step, state, grads)
     # period 2: the comm branch's kernels sit beside the local Adam's
     assert text.count("tpu_custom_call") >= 2
+
+
+def test_packed_deepfm_step_keeps_the_table_lane_dense(one_chip, monkeypatch):
+    """The packed DeepFM training step reads and writes its (N, 10)
+    embedding table only as the lane-dense rows of the packed buffer. No
+    instruction is the table viewed (K, N, E) (on a TPU lane-padded to
+    128), its transposed gradient (E, K*N), a flat leaf of N*E or K*N*E
+    elements or a K-merged first-order table of K*N, and no ``while`` loop
+    moves them. At this size the compiler made all of those before the
+    lookup read the buffer's rows."""
+    monkeypatch.setattr(ops, "_interpret",
+                        lambda override=None: bool(override))
+    N, F, E, B = 200_000, 39, 10, 64
+    opt = make_optimizer("d-adam", K=K, eta=1e-3, period=4, topology="ring",
+                         backend="pallas")
+    trainer = DecentralizedTrainer(deepfm_loss, opt)
+    params = jax.eval_shape(lambda k: init_deepfm(k, N, F, E),
+                            jax.random.PRNGKey(0))
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+    state = place(jax.eval_shape(trainer.init, params))
+    batch = place({"feat_ids": jax.ShapeDtypeStruct((K, B, F), jnp.int32),
+                   "label": jax.ShapeDtypeStruct((K, B), jnp.int32)})
+    text = trainer.lower_step(state, batch).compile().as_text()
+    shapes = {tuple(map(int, s.split(",")))
+              for s in re.findall(r"f32\[([0-9,]+)\]", text)}
+    padded = {(K, N, E), (E, K * N), (K * N * E,)}
+    flat = {N * E, K * N}
+    assert not shapes & padded
+    assert not [s for s in shapes if flat & set(s)]
+    assert " while(" not in text
