@@ -255,15 +255,34 @@ def step_program(ctx) -> Optional[StepProgram]:
     return ctx.step_program
 
 
+def step_ops(device, ctx, scope: str, keep
+             ) -> Optional[List[Tuple[trace.Op, float]]]:
+    """(op, self time ns) of the step's ops on one chip that ``keep(op,
+    names, backward)`` accepts; None where no op of the step is under
+    ``scope``."""
+    prog = step_program(ctx)
+    if prog is None or not prog.has_scope(scope):
+        return None
+    return [(op, t) for op, t, names, bwd in prog.ops(device)
+            if keep(op, names, bwd)]
+
+
 def scoped_ms(device, ctx, scope: str, keep) -> Optional[float]:
     """Self time per step, ms, of the step's ops that ``keep(op, names,
     backward)`` accepts; None where no op of the step is under ``scope``."""
-    prog = step_program(ctx)
-    if prog is None or not ctx.steps or not prog.has_scope(scope):
+    ops = step_ops(device, ctx, scope, keep)
+    if ops is None or not ctx.steps:
         return None
-    total = sum(t for op, t, names, bwd in prog.ops(device)
-                if keep(op, names, bwd))
-    return total / ctx.steps / 1e6
+    return sum(t for _, t in ops) / ctx.steps / 1e6
+
+
+def opt_kernels(device, ctx) -> List[trace.Op]:
+    """The step's Mosaic kernel events on one chip that lie under the
+    program's ``opt`` scope: the optimizer's kernels, and not a kernel
+    of the model's."""
+    ops = step_ops(device, ctx, "opt", lambda op, names, bwd:
+                   "opt" in names and ctx.is_kernel(op))
+    return [op for op, _ in ops or ()]
 
 
 # ------------------------------ host spans ----------------------------------

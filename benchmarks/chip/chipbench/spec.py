@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import pathlib
@@ -57,12 +58,27 @@ def load_cell(name: str, bench: Optional[dict] = None,
 
 
 def load_module(path: pathlib.Path):
-    """Import one reader or cost file by its path."""
+    """Import one reader, cost or family file by its path."""
     spec = importlib.util.spec_from_file_location(
         f"chipbench_{path.parent.name}_{path.stem}".replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@functools.lru_cache(maxsize=None)
+def family(name: str):
+    """The module of a configuration family, ``families/<name>.py``: the
+    program's loss, the seeded weights, the plain reference, the model
+    FLOPs, the traffic generator and the small CPU sizes of every
+    configuration whose file says ``"family": name``."""
+    path = HERE / "families" / f"{name}.py"
+    if not path.is_file():
+        have = sorted(p.name for p in (HERE / "families").glob("*.py"))
+        raise ValueError(f"unknown configuration family {name!r}: no "
+                         f"benchmarks/chip/families/{name}.py (have "
+                         f"{have})")
+    return load_module(path)
 
 
 def peaks_for(device_kind: str) -> Dict[str, Any]:

@@ -3,57 +3,20 @@ files: the program's model loss, its optimizer and its
 ``DecentralizedTrainer``, as a user of the program builds them. Also the
 configuration's plain reference loss and its model FLOPs per step.
 
-This is the one module of the benchmark that imports the program.
+What belongs to one model family (the program's loss, the seeded weights,
+the reference, the FLOPs) comes from the configuration's family file,
+``families/<family>.py``; this module builds the optimizer and the
+trainer around it and names no family.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 from typing import Any, Callable, Optional
 
 import jax
 
-from reference import deepfm as ref_deepfm
-from reference import transformer as ref_lm
-from reference import weights
-from chipbench.traffic import field_rows
-
-
-def lm_sizes(config: dict) -> dict:
-    """The sizes of an ``lm`` configuration file (Hugging Face keys)."""
-    d, H = config["hidden_size"], config["num_attention_heads"]
-    return {"n_layers": config["num_hidden_layers"], "d_model": d,
-            "n_heads": H, "n_kv_heads": config["num_key_value_heads"],
-            "head_dim": config.get("head_dim", d // H),
-            "d_ff": config["intermediate_size"],
-            "vocab_size": config["vocab_size"],
-            "rope_theta": float(config["rope_theta"]),
-            "norm_eps": float(config["rms_norm_eps"])}
-
-
-def model_flops_per_example(config: dict, traffic: dict) -> float:
-    """Forward and backward FLOPs of one worker's example as the model
-    requires them: 6 per matmul parameter per token (the LM head included,
-    the embedding gather not), plus causal attention; for DeepFM, 6 per
-    MLP parameter and per active FM and linear weight. Nothing recomputed
-    and no optimizer arithmetic is counted."""
-    if config["family"] == "lm":
-        c = lm_sizes(config)
-        d, hd, S = c["d_model"], c["head_dim"], traffic["seq_len"]
-        per_layer = (d * hd * (c["n_heads"] + 2 * c["n_kv_heads"])
-                     + c["n_heads"] * hd * d + 3 * d * c["d_ff"])
-        n = c["n_layers"] * per_layer + d * c["vocab_size"]
-        # QK^T and PV: 2 * 2 * S^2 * H * hd forward, half of it causal
-        attn = c["n_layers"] * 2 * S * S * c["n_heads"] * hd
-        return 6.0 * n * S + 3.0 * attn
-    fields = config["n_numeric"] + len(config["table_rows"])
-    E, widths = config["embed_dim"], list(config["hidden"]) + [1]
-    mlp, d_in = 0, fields * E
-    for h in widths:
-        mlp += d_in * h + h
-        d_in = h
-    return 6.0 * (mlp + fields * E + fields)
+from chipbench import spec
 
 
 @dataclasses.dataclass
@@ -83,38 +46,8 @@ def build(config: dict, traffic: dict, *, mesh: Any = None,
 
     o = traffic["optimizer"]
     K = o["workers"]
-    fam = config["family"]
-    if fam == "deepfm":
-        from repro.models.deepfm import deepfm_loss
-        rows = sum(field_rows(config))
-        fields = config["n_numeric"] + len(config["table_rows"])
-        loss = deepfm_loss
-        E, hidden = config["embed_dim"], tuple(config["hidden"])
-        make_params = lambda key: weights.deepfm(  # noqa: E731
-            key, rows, fields, E, hidden)
-        ref_loss = ref_deepfm.loss
-    elif fam == "lm":
-        import jax.numpy as jnp
-
-        from repro.configs.base import ModelConfig
-        from repro.models import build_model
-        c = lm_sizes(config)
-        cfg = ModelConfig(
-            arch_id=config["name"], family="dense", n_layers=c["n_layers"],
-            d_model=c["d_model"], n_heads=c["n_heads"],
-            n_kv_heads=c["n_kv_heads"], head_dim=c["head_dim"],
-            d_ff=c["d_ff"], vocab_size=c["vocab_size"],
-            rope_theta=c["rope_theta"], norm_eps=c["norm_eps"],
-            tie_embeddings=config["tie_word_embeddings"],
-            param_dtype=jnp.dtype(config["param_dtype"]),
-            compute_dtype=jnp.dtype(config["compute_dtype"]))
-        api = build_model(cfg)
-        loss = api.loss
-        make_params = functools.partial(
-            weights.lm, sizes=tuple(sorted(c.items())))
-        ref_loss = functools.partial(ref_lm.loss, cfg=c)
-    else:
-        raise ValueError(f"unknown configuration family {fam!r}")
+    fam = spec.family(config["family"])
+    loss = fam.program_loss(config)
     if loss_wrap is not None:
         loss = loss_wrap(loss)
     opt_kw = {k: o[k] for k in ("eta", "beta1", "beta2", "tau", "period")}
@@ -128,6 +61,6 @@ def build(config: dict, traffic: dict, *, mesh: Any = None,
     trainer = DecentralizedTrainer(lambda p, b: loss(p, b), opt,
                                    donate=True)
     batch = traffic["batch_per_worker"]
-    return System(trainer, make_params, ref_loss,
+    return System(trainer, fam.make_params(config), fam.ref_loss(config),
                   config.get("matmul_precision"),
-                  model_flops_per_example(config, traffic) * batch * K)
+                  fam.flops_per_example(config, traffic) * batch * K)

@@ -1,19 +1,19 @@
-"""The one general generator of training batches, driven by a traffic
-file's parameters and the seed. It makes a pool of distinct batches on the
-device in one jitted call; the window cycles through the pool, so it
-measures the trainer and not an input pipeline.
+"""The general generators of training batches, driven by a traffic
+file's parameters and the seed. Each makes a pool of distinct batches on
+the device in one jitted call; the window cycles through the pool, so it
+measures the trainer and not an input pipeline. A configuration's family
+file (``families/<family>.py``) picks the generator.
 
-Two kinds of batch, chosen by the configuration's family:
-
-* ``ctr`` (DeepFM): one id per field. Field f draws a popularity rank
-  from a Zipf law of exponent ``zipf_s`` over its ``n_f`` rows (the
-  continuous inverse CDF, floored), and worker k maps ranks to rows
-  through its own random permutation of the field, so the workers'
-  popular rows differ (non-IID, as in the paper). Labels are Bernoulli
-  at ``click_rate``.
-* ``lm``: tokens uniform over the (sliced) vocabulary, of which a share
-  ``0.5 * min(skew, 1)`` is folded into the worker's own band of
-  ``vocab // K`` ids (the non-IID skew of the program's ``lm_batch``).
+* ``ctr_pool`` (click-through rate, one id per field): field f draws a
+  popularity rank from a Zipf law of exponent ``zipf_s`` over its ``n_f``
+  rows (the continuous inverse CDF, floored), and worker k maps ranks to
+  rows through its own random permutation of the field, so the workers'
+  popular rows differ (non-IID, as in the paper). Labels are Bernoulli at
+  ``click_rate``.
+* ``lm_pool`` (next-token prediction): tokens uniform over the (sliced)
+  vocabulary, of which a share ``0.5 * min(skew, 1)`` is folded into the
+  worker's own band of ``vocab // K`` ids (the non-IID skew of the
+  program's ``lm_batch``).
 """
 from __future__ import annotations
 
@@ -24,9 +24,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import spec
+
 
 def field_rows(config: dict) -> List[int]:
-    """Rows of each field of a ``deepfm`` configuration, numeric fields
+    """Rows of each field of a CTR configuration, numeric fields
     first (the Criteo column order: I1-I13, C1-C26), tables capped at
     ``max_ind_range`` (DLRM's hashing of a table into that many rows)."""
     cap = config["max_ind_range"]
@@ -80,18 +82,25 @@ def _lm_pool(key, vocab: int, K: int, B: int, seq: int, pool: int,
     return tuple({"tokens": toks[i]} for i in range(pool))
 
 
+def ctr_pool(key: jax.Array, config: dict, traffic: dict) -> Sequence[dict]:
+    """``traffic['pool']`` distinct CTR batches of ``feat_ids`` and
+    ``label``, each with a leading worker dim K on every leaf."""
+    return _ctr_pool(key, tuple(field_rows(config)),
+                     traffic["optimizer"]["workers"],
+                     traffic["batch_per_worker"], traffic["pool"],
+                     float(traffic["zipf_s"]), float(traffic["click_rate"]))
+
+
+def lm_pool(key: jax.Array, config: dict, traffic: dict) -> Sequence[dict]:
+    """``traffic['pool']`` distinct batches of ``seq_len + 1`` tokens,
+    each with a leading worker dim K on every leaf."""
+    return _lm_pool(key, config["vocab_size"],
+                    traffic["optimizer"]["workers"],
+                    traffic["batch_per_worker"], traffic["seq_len"],
+                    traffic["pool"], float(traffic["skew"]))
+
+
 def make_pool(key: jax.Array, config: dict, traffic: dict) -> Sequence[dict]:
-    """``traffic['pool']`` distinct batches, each with a leading worker
-    dim K on every leaf."""
-    K = traffic["optimizer"]["workers"]
-    if config["family"] == "deepfm":
-        return _ctr_pool(key, tuple(field_rows(config)), K,
-                         traffic["batch_per_worker"], traffic["pool"],
-                         float(traffic["zipf_s"]),
-                         float(traffic["click_rate"]))
-    if config["family"] == "lm":
-        return _lm_pool(key, config["vocab_size"], K,
-                        traffic["batch_per_worker"], traffic["seq_len"],
-                        traffic["pool"], float(traffic["skew"]))
-    raise ValueError(f"no traffic generator for family "
-                     f"{config['family']!r}")
+    """The cell's pool, from the generator its configuration's family
+    picks."""
+    return spec.family(config["family"]).make_pool(key, config, traffic)

@@ -1,12 +1,14 @@
-"""Layer: optimizer kernels. Share of the kernels' roofline: for each
-kernel call, the least time the chip could take, max(FLOPs / peak FLOP/s,
-required HBM bytes / peak bytes/s), summed over the window and divided
-by the kernels' summed device time. FLOPs and bytes come from the
-kernel's file under benchmarks/chip/kernels/."""
+"""Layer: optimizer kernels. Share of the roofline of the Mosaic kernels
+under the program's ``opt`` scope (as ``opt_kernel_ms`` counts them): for
+each kernel call, the least time the chip could take, max(FLOPs / peak
+FLOP/s, required HBM bytes / peak bytes/s), summed over the window and
+divided by the kernels' summed device time. FLOPs and bytes come from
+the kernel's file under benchmarks/chip/kernels/."""
+from chipbench.marks import opt_kernels
 
 
 def read(device, ctx):
-    ks = [o for o in device.ops if ctx.is_kernel(o)]
+    ks = opt_kernels(device, ctx)
     if not ks:
         return None
     least = 0.0

@@ -72,8 +72,8 @@ def swiglu(layer: dict, x: jax.Array, mode: str) -> jax.Array:
 
 def loss(params: dict, batch: dict, cfg: dict, mode: str = "highest"
          ) -> jax.Array:
-    """batch: {'tokens': (B, S + 1) int32}; cfg: the sizes of
-    ``chipbench.systems.lm_sizes``."""
+    """batch: {'tokens': (B, S + 1) int32}; cfg: the ``lm``
+    family's ``sizes``."""
     tokens = batch["tokens"]
     inputs, labels = tokens[:, :-1], tokens[:, 1:]
     h = params["embed"][inputs].astype(jnp.float32)

@@ -46,7 +46,8 @@ def deepfm(key, rows: int, fields: int, embed: int, hidden: tuple) -> dict:
 
 @functools.partial(jax.jit, static_argnums=(1,))
 def lm(key, sizes: tuple) -> dict:
-    """``sizes``: sorted ``(name, value)`` pairs of ``systems.lm_sizes``."""
+    """``sizes``: the sorted ``(name, value)`` pairs of the ``lm``
+    family's ``sizes``."""
     c = dict(sizes)
     L, d, F, V = c["n_layers"], c["d_model"], c["d_ff"], c["vocab_size"]
     hq, hkv = c["n_heads"] * c["head_dim"], c["n_kv_heads"] * c["head_dim"]

@@ -13,15 +13,15 @@ import json
 import pytest
 
 import calibrate
-from small import PENDING, cell_names, pending_cell, small_cell
+from small import cell_names, small_cell
 
 
-@pytest.mark.parametrize("name", cell_names() + sorted(PENDING))
+@pytest.mark.parametrize("name", cell_names())
 def test_control_and_faults_separate_from_the_program(name, tmp_path):
-    """For the pending four-chip cell this is also its reference's check
-    against the program (CD-Adam over four host devices)."""
+    """For the four-chip cell this is also its reference's check against
+    the program (CD-Adam over four host devices)."""
     out = tmp_path / "cal.json"
-    cell = pending_cell(name) if name in PENDING else small_cell(name)
+    cell = small_cell(name)
     rc = calibrate.main(["--workload", name, "--seeds", "1",
                          "--control-seeds", "1", "--first-seed",
                          str(2 ** 35 + 3), "--out", str(out)],

@@ -8,47 +8,26 @@ calls, the step program's runs and each of its instructions' opcode and
 scope path), the readers are checked against values worked out by other
 means: a mask over the window at 10 ns resolution in which each bin
 belongs to the innermost op covering it. On the older recording of a
-program without marks (``data/deepfm-3-steps.json``) the new readers
-report nothing and the others read what they read before."""
+program without marks (``data/deepfm-3-steps.json``) the readers of
+scopes and spans report nothing and the others read what they read
+before."""
 from __future__ import annotations
-
-import json
-import pathlib
 
 import numpy as np
 import pytest
 
-from chipbench import layers, marks, spec, trace
+from chipbench import layers, marks, trace
+from recorded import context, load
 
-DATA = pathlib.Path(__file__).parent / "data"
-FLOPS_PER_STEP = 1.1748e10        # systems.model_flops_per_example x 4096
 RES = 10.0                        # ns per bin of the mask
 NEW = ["fwd_ms", "bwd_ms", "packed_view_ms", "opt_glue_ms", "gossip_ms",
        "log_idle_ms", "dispatch_idle_ms"]
 
 
-def _context(fix):
-    ops = sorted((trace.Op(n, s, e, k) for n, k, s, e in fix["ops"]),
-                 key=lambda o: (o.start, -o.end))
-    tr = trace.Trace([trace.Device(0, ops)], tuple(fix["window"]),
-                     [trace.Op(n, s, e) for n, s, e in fix["host"]])
-    calls = {k: trace.KernelCall(
-        k, c["kernel"], tuple(trace.Array(d, tuple(s)) for d, s in
-                              c["operands"]),
-        tuple(trace.Array(d, tuple(s)) for d, s in c["results"]),
-        c["arity"]) for k, c in fix["calls"].items()}
-    return layers.Context(tr, fix["steps"], spec.peaks_for("TPU v5 lite"),
-                          FLOPS_PER_STEP, calls, layers.kernel_modules())
-
-
 @pytest.fixture(scope="module")
 def scoped():
-    fix = json.loads((DATA / "deepfm-3-steps-scoped.json").read_text())
-    ctx = _context(fix)
-    ctx.step_program = marks.StepProgram(
-        {n: tuple(v) for n, v in fix["scopes"].items()},
-        {0: [tuple(r) for r in fix["runs"]]})
-    return fix, ctx
+    fix = load("deepfm-3-steps-scoped.json")
+    return fix, context(fix, scoped=True)
 
 
 def _innermost_ms(fix, keep, step=True):
@@ -129,16 +108,42 @@ def test_idle_readers_against_a_mask(scoped):
         assert got[name] == pytest.approx(want, rel=1e-2, abs=1e-4), name
 
 
+def test_opt_kernel_readers_count_only_the_opt_scope(scoped):
+    """Every kernel of the recorded step is the optimizer's: the readings
+    equal those of the readers that counted every Mosaic kernel. A
+    kernel put under ``model`` drops out of both."""
+    fix, ctx = scoped
+    names = ["opt_kernel_ms", "opt_kernel_roofline"]
+    old = {"opt_kernel_ms": 6.43939,                  # the readers before
+           "opt_kernel_roofline": 68.64934796406567}  # the opt scope
+    got = layers.read_all(names, ctx)
+    for n in names:
+        assert got[n] == pytest.approx(old[n], rel=1e-12), n
+    prog = ctx.step_program
+    gossip = [o for o in ctx.trace.devices[0].ops if o.name == "gossip.1"]
+    assert len(gossip) == 1
+    ctx.step_program = marks.StepProgram(
+        dict(prog.scopes, **{"gossip.1": (
+            "custom-call", "jit(step)/grad/model/pallas_call")}), prog.runs)
+    try:
+        moved = layers.read_all(names, ctx)
+    finally:
+        ctx.step_program = prog
+    assert moved["opt_kernel_ms"] == pytest.approx(
+        old["opt_kernel_ms"] - gossip[0].dur / fix["steps"] / 1e6,
+        rel=1e-12)
+    assert moved["opt_kernel_roofline"] != pytest.approx(
+        old["opt_kernel_roofline"], rel=1e-6)
+
+
 def test_old_recording_new_readers_silent_others_unchanged():
-    fix = json.loads((DATA / "deepfm-3-steps.json").read_text())
-    ctx = _context(fix)
-    ctx.step_program = None           # a program without scopes
-    got = layers.read_all(NEW, ctx)
-    assert got == {n: None for n in NEW}
+    """The optimizer kernels' readers need the ``opt`` scope too."""
+    ctx = context(load("deepfm-3-steps.json"))   # a program without scopes
+    silent = NEW + ["opt_kernel_ms", "opt_kernel_roofline"]
+    got = layers.read_all(silent, ctx)
+    assert got == {n: None for n in silent}
     before = {"device_idle_share": 0.008367200329262303,
               "xla_ops_ms": 99.48147233333333,
-              "opt_kernel_ms": 6.439162666666667,
-              "opt_kernel_roofline": 68.65177161538676,
               "mfu": 0.05629642234200215, "collective_exposed_ms": None}
     got = layers.read_all(list(before), ctx)
     for name, v in before.items():

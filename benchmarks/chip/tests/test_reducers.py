@@ -2,40 +2,33 @@
 trace: three steps of the DeepFM cell on a TPU v5 lite, cut from a
 ``--trace 1`` run (``data/deepfm-3-steps.json``: the ``XLA Ops`` events
 by instruction and opcode, the host spans, the kernel calls of the
-compiled step). The expected values are worked out here by other means:
-a busy mask over the window at 10 ns resolution, and sums over the
-events by kind."""
+compiled step; the optimizer kernels' readers, which need the step's
+scopes, against ``data/deepfm-3-steps-scoped.json``). The expected
+values are worked out here by other means: a busy mask over the window
+at 10 ns resolution, and sums over the events by kind."""
 from __future__ import annotations
-
-import json
-import pathlib
 
 import numpy as np
 import pytest
 
 from chipbench import layers, spec, trace
+from recorded import FLOPS_PER_STEP, context, load
 
-DATA = pathlib.Path(__file__).parent / "data" / "deepfm-3-steps.json"
-FLOPS_PER_STEP = 1.1748e10        # systems.model_flops_per_example x 4096
 RES = 10.0                        # ns per bin of the mask
 
 
 @pytest.fixture(scope="module")
 def recorded():
-    fix = json.loads(DATA.read_text())
-    ops = sorted((trace.Op(n, s, e, k) for n, k, s, e in fix["ops"]),
-                 key=lambda o: (o.start, -o.end))
-    tr = trace.Trace([trace.Device(0, ops)], tuple(fix["window"]),
-                     [trace.Op(n, s, e) for n, s, e in fix["host"]])
-    calls = {k: trace.KernelCall(
-        k, c["kernel"], tuple(trace.Array(d, tuple(s)) for d, s in
-                              c["operands"]),
-        tuple(trace.Array(d, tuple(s)) for d, s in c["results"]),
-        c["arity"]) for k, c in fix["calls"].items()}
-    costs = layers.kernel_modules()
-    ctx = layers.Context(tr, fix["steps"], spec.peaks_for("TPU v5 lite"),
-                         FLOPS_PER_STEP, calls, costs)
-    return fix, ctx
+    fix = load("deepfm-3-steps.json")
+    return fix, context(fix)
+
+
+@pytest.fixture(scope="module")
+def scoped():
+    """The recording of a program with scopes: the optimizer's kernels
+    are those under ``opt``."""
+    fix = load("deepfm-3-steps-scoped.json")
+    return fix, context(fix, scoped=True)
 
 
 def _mask(fix, keep):
@@ -56,8 +49,8 @@ def test_idle_share_and_busy_time(recorded):
     assert 0 < got < 5
 
 
-def test_kernel_time_and_roofline(recorded):
-    fix, ctx = recorded
+def test_kernel_time_and_roofline(scoped):
+    fix, ctx = scoped
     kernels = [(n, e - s) for n, k, s, e in fix["ops"] if n in fix["calls"]]
     assert len(kernels) == 3          # fused_adam twice, the gossip once
     want_ms = sum(d for _, d in kernels) / 3 / 1e6
