@@ -41,7 +41,7 @@ def main(argv=None, *, require_tpu: bool = True, cell=None) -> int:
                            cell=cell)
     if isinstance(opened, int):
         return opened
-    cell = opened[0]
+    cell, devs = opened[0], opened[1]
     import jax
 
     from chipbench import check, traffic
@@ -55,6 +55,8 @@ def main(argv=None, *, require_tpu: bool = True, cell=None) -> int:
     out = {"workload": cell.name, "control_mode": mode, "program": [],
            "control": [], **{f"fault_{f}": [] for f in FAULTS}}
     seeds = [args.first_seed + 7919 * i for i in range(args.seeds)]
+    # the reference on the cell's chips, as run.py judges it
+    used = devs[:cell.chips]
 
     def key_pair(seed):
         return jax.random.split(seed_key(seed))
@@ -80,7 +82,8 @@ def main(argv=None, *, require_tpu: bool = True, cell=None) -> int:
             del state, feed
             gc.collect()
             ref = check.reference_readings(system, cell.config, cell.traffic,
-                                           key_w, first_batches(key_d))
+                                           key_w, first_batches(key_d),
+                                           devices=used)
             vals = check.compare(prog, ref)
             out["program"].append({"seed": seed, **vals})
             print(f"[calibrate] program seed {seed}: {vals} "
@@ -88,12 +91,13 @@ def main(argv=None, *, require_tpu: bool = True, cell=None) -> int:
         for seed in seeds[:args.control_seeds]:
             key_w, key_d = key_pair(seed)
             ref = check.reference_readings(system, cell.config, cell.traffic,
-                                           key_w, first_batches(key_d))
+                                           key_w, first_batches(key_d),
+                                           devices=used)
             for tag, kw in [("control", {"mode": mode})] + [
                     (f"fault_{f}", {"fault": f}) for f in FAULTS]:
                 got = check.reference_readings(
                     system, cell.config, cell.traffic, key_w,
-                    first_batches(key_d), **kw)
+                    first_batches(key_d), devices=used, **kw)
                 vals = check.compare(got, ref)
                 out[tag].append({"seed": seed, **vals})
                 print(f"[calibrate] {tag} seed {seed}: {vals}", flush=True)

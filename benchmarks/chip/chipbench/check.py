@@ -27,6 +27,7 @@ same seed. Three numbers are compared, each against its limit:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -59,6 +60,29 @@ def _change_norms(stacked, p0):
         lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32)[None],
         stacked, p0)
     return _norms(diff)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _as_row(x, k: int, K: int):
+    """``x`` as row k of a K-stacked leaf whose other rows are zero."""
+    return jnp.pad(x[None], [(k, K - 1 - k)] + [(0, 0)] * x.ndim)
+
+
+def worker_norms(tree, k: int, K: int, p0=None) -> np.ndarray:
+    """Worker k's column of ``stacked_norms`` (of ``change_norms`` with
+    ``p0``) over the K workers' stacked trees, from worker k's tree alone
+    and on its device. Each leaf is reduced as row k of a K-stacked leaf,
+    one leaf at a time: a tree stacked as a worker of one is reduced in
+    another order on a TPU."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    bases = ([None] * len(leaves) if p0 is None
+             else jax.tree_util.tree_leaves(p0))
+    out = []
+    for x, b in zip(leaves, bases):
+        row = {"x": _as_row(x, k, K)}
+        n = _norms(row) if b is None else _change_norms(row, {"x": b})
+        out.append(float(n[0, k]))
+    return np.asarray(out, np.float64)
 
 
 def stacked_norms(stacked) -> np.ndarray:
@@ -154,13 +178,16 @@ def _half(x: jax.Array) -> jax.Array:
 
 def reference_readings(system, config: dict, traffic: dict, key_w, batches:
                        Sequence[dict], *, mode: str = "highest",
-                       fault: Optional[str] = None) -> Readings:
+                       fault: Optional[str] = None,
+                       devices: Optional[Sequence] = None) -> Readings:
     """The plain reference over ``batches`` (K-stacked, one per step).
 
     ``mode`` below ``highest`` is the control; ``fault`` plants one in
     the reference put in the program's place: ``'half'`` (half of each
     worker's batch left out, the mean over the rest) or ``'no_gossip'``
-    (the exchange left out)."""
+    (the exchange left out). Worker k runs on ``devices[k %
+    len(devices)]``, all of them on the default device without
+    ``devices``; each worker's norms are taken on its own device."""
     from reference import cdadam, dadam
 
     o = traffic["optimizer"]
@@ -177,18 +204,19 @@ def reference_readings(system, config: dict, traffic: dict, key_w, batches:
     p0 = system.make_params(key_w)
     per_step = [[jax.tree_util.tree_map(lambda x, k=k: x[k], b)
                  for k in range(K)] for b in batches]
+    kw = {"first_norms": functools.partial(worker_norms, K=K),
+          "devices": devices}
     if o["name"] == "d-adam":
         losses, first, xs = dadam.run(p0, per_step, grad_fn, hp,
-                                      gossip=fault != "no_gossip")
+                                      gossip=fault != "no_gossip", **kw)
     elif o["name"] == "cd-adam" and o["compressor"] == "sign":
         losses, first, xs = cdadam.run(p0, per_step, grad_fn, hp,
                                        gamma=o["gamma"],
-                                       exchange=fault != "no_gossip")
+                                       exchange=fault != "no_gossip", **kw)
     else:
         raise ValueError(f"no reference for optimizer {o['name']!r}")
-    stack = lambda ts: jax.tree_util.tree_map(      # noqa: E731
-        lambda *a: jnp.stack(a), *ts)
-    grad = stacked_norms(stack(first))
-    del first
-    change = change_norms(stack(xs), p0)
-    return Readings(losses, grad, change)
+    change = []
+    for k, x in enumerate(xs):
+        p0_k = dadam.put(p0, dadam.device_of(devices, k))
+        change.append(worker_norms(x, k, K, p0_k))
+    return Readings(losses, np.stack(first, axis=1), np.stack(change, axis=1))

@@ -13,10 +13,15 @@ so every estimate of worker k moves by the same ``q_k`` and one estimate
 per worker stands for all its copies. ``exchange=False`` plants a fault:
 no worker receives its neighbours' ``q``, so their estimates never move
 from zero on its side.
+
+Worker k's estimate ``xhat_k`` (and its own view of it under the fault)
+lives on worker k's device with its x, m and v; the round moves a
+neighbour's estimate to worker k's device one leaf at a time.
 """
 from __future__ import annotations
 
-from typing import Callable, Sequence
+import functools
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -33,45 +38,53 @@ _adam_tree = dadam._adam_tree
 
 
 def run(params0, batches: Sequence[Sequence], grad_fn: Callable, hp: dict,
-        *, gamma: float, exchange: bool = True):
+        *, gamma: float, first_norms: Callable, exchange: bool = True,
+        devices: Optional[Sequence] = None):
     """As :func:`reference.dadam.run`, with compressed gossip."""
     K = len(batches[0])
     W = dadam.ring_weights(K)
     tm = jax.tree_util.tree_map
-    xs = [params0] * K
-    ms = [tm(jnp.zeros_like, params0)] * K
-    vs = list(ms)
-    hats = [tm(jnp.zeros_like, params0)] * K       # xhat_k, as all hold it
+    devs = [dadam.device_of(devices, k) for k in range(K)]
+    xs = [dadam.fresh(params0, d) for d in devs]
+    ms = [tm(jnp.zeros_like, x) for x in xs]
+    vs = [tm(jnp.zeros_like, x) for x in xs]
+    hats = [tm(jnp.zeros_like, x) for x in xs]     # xhat_k, as all hold it
     own = list(hats)                               # fault: k's own view
-    losses, first = [], None
+
+    def consensus(k, x, hk, *hs):
+        """One leaf of x_k + gamma sum_j W[k, j] (xhat_j - xhat_k), the
+        neighbours j in increasing order."""
+        acc = jnp.zeros_like(x)
+        for j in range(K):
+            if j == k or W[k, j] <= 0:
+                continue
+            hj = dadam.put(hs[j], devs[k]) if exchange else jnp.zeros_like(x)
+            acc = jax.block_until_ready(acc + float(W[k, j]) * (hj - hk))
+        return jax.block_until_ready(x + gamma * acc)
+
+    def advance(h, x):
+        """One leaf of xhat + Q(x - xhat)."""
+        return jax.block_until_ready(jnp.add(h, scaled_sign(x - h)))
+
+    losses, first = [], []
     for t, step_batches in enumerate(batches, start=1):
-        out = [grad_fn(xs[k], step_batches[k]) for k in range(K)]
+        out = [grad_fn(xs[k], dadam.put(step_batches[k], devs[k]))
+               for k in range(K)]
         losses.append(float(np.mean([float(l) for l, _ in out])))
-        if first is None:
-            first = [g for _, g in out]
+        if t == 1:
+            first = [first_norms(g, k) for k, (_, g) in enumerate(out)]
         for k in range(K):
             xs[k], ms[k], vs[k] = dadam.split3(
                 _adam_tree(xs[k], out[k][1], ms[k], vs[k], hp))
         del out
         if t % hp["period"]:
             continue
-        view = hats if exchange else None
-        new_x = []
-        for k in range(K):
-            nbr = [j for j in range(K) if j != k and W[k, j] > 0]
-            acc = tm(jnp.zeros_like, xs[k])
-            for j in nbr:
-                hj = view[j] if exchange else tm(jnp.zeros_like, xs[k])
-                hk = hats[k] if exchange else own[k]
-                acc = tm(lambda a, x, y, w=float(W[k, j]): a + w * (x - y),
-                         acc, hj, hk)
-            new_x.append(tm(lambda x, a: x + gamma * a, xs[k], acc))
-        xs = new_x
         base = hats if exchange else own
-        q = [tm(lambda x, h: scaled_sign(x - h), xs[k], base[k])
-             for k in range(K)]
+        xs = [tm(functools.partial(consensus, k), xs[k], base[k], *hats)
+              for k in range(K)]
+        base = [tm(advance, base[k], xs[k]) for k in range(K)]
         if exchange:
-            hats = [tm(jnp.add, hats[k], q[k]) for k in range(K)]
+            hats = base
         else:
-            own = [tm(jnp.add, own[k], q[k]) for k in range(K)]
+            own = base
     return losses, first, xs

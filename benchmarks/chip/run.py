@@ -126,6 +126,14 @@ def open_cell(workload: str, *, require_tpu: bool = True, cell=None):
     return cell, devs, peaks, counter
 
 
+def _memory(device) -> str:
+    stats = device.memory_stats()
+    if not stats:
+        return f"device {device.id} not reported"
+    return (f"device {device.id} {stats.get('bytes_in_use')} / "
+            f"{stats.get('peak_bytes_in_use')}")
+
+
 def build_system(cell, **build_kw):
     """The program's trainer for the cell (on a worker mesh for comm
     'axis')."""
@@ -245,11 +253,13 @@ def main(argv=None, *, require_tpu: bool = True, build_kw=None,
         batches = list(traffic.make_pool(key_d, cell.config, cell.traffic)
                        )[:first_steps]
         ref = check.reference_readings(system, cell.config, cell.traffic,
-                                       key_w, batches)
+                                       key_w, batches, devices=used)
         values = check.compare(prog, ref)
         correct, checks = check.judge(values, cell.limits)
         log(f"reference {time.perf_counter() - t_ref:.1f} s; program "
             f"losses {prog.losses}; reference losses {ref.losses}")
+        log("after the reference, bytes in use / peak since start: " +
+            ", ".join(_memory(d) for d in used))
 
     result = {"correct": correct, "attempted": steps, "failed": failed,
               "metrics": metrics,
