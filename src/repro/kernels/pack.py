@@ -538,18 +538,29 @@ def _lane_rows(buf: jax.Array, ids: jax.Array, valid: jax.Array, e: int,
     """``(len(ids), e)`` rows, ``e < LANE``, of the table whose flat
     elements start at element ``start * LANE`` of ``buf`` (``(rows,
     LANE)``) and lie in its rows ``[start, stop)``: row ``i`` is ``e``
-    elements at ``i * e`` from there, inside one buffer row when ``e``
-    divides LANE and inside two otherwise. Those rows are gathered whole,
-    and ``e`` lanes are selected out of them by a masked sum (one term is
-    nonzero, so it is exact)."""
-    win = 1 if LANE % e == 0 else 2
-    first = start * LANE + ids * e
-    r = jnp.minimum(first // LANE, stop - win)
-    off = first - r * LANE                       # in [0, win*LANE - e]
-    windows = jnp.concatenate([buf[r + i] for i in range(win)], axis=-1)
-    want = off[:, None, None] + jnp.arange(e)[None, :, None]
-    hit = jnp.arange(win * LANE)[None, None, :] == want
+    elements at ``i * e`` from there, inside the window of buffer rows
+    :func:`_window` gives. Those rows are gathered whole, and ``e`` lanes
+    are selected out of them by a masked sum (one term is nonzero, so it
+    is exact)."""
+    win, r, off, hit = _window(ids * e, e, stop - start)
+    windows = jnp.concatenate([buf[start + r + i] for i in range(win)],
+                              axis=-1)
     return jnp.sum(jnp.where(hit, windows[:, None, :], 0), axis=-1)
+
+
+def _window(at: jax.Array, e: int, rows: int):
+    """``(win, r, off, hit)``: the ``e`` elements at flat offset ``at`` of
+    a block of ``rows`` buffer rows lie at lanes ``[off, off + e)`` of its
+    rows ``[r, r + win)``, and ``hit[j, c, l]`` (``(len(at), e, win *
+    LANE)``) says that lane ``l`` of id ``j``'s window holds its element
+    ``c``. One row holds them when ``e`` divides LANE or the block is one
+    row; two may otherwise, and ``r`` is clamped so that both lie in the
+    block (``off`` is then in ``[0, win * LANE - e]``)."""
+    win = 1 if LANE % e == 0 or rows < 2 else 2
+    r = jnp.minimum(at // LANE, rows - win)
+    off = at - r * LANE
+    want = off[:, None, None] + jnp.arange(e)[None, :, None]
+    return win, r, off, jnp.arange(win * LANE)[None, None, :] == want
 
 
 def _lane_rows_fwd(buf, ids, valid, e, start, stop):
@@ -558,57 +569,36 @@ def _lane_rows_fwd(buf, ids, valid, e, start, stop):
 
 
 def _lane_rows_bwd(e, start, stop, res, ct):
-    """The gradient lands in a block of the table's own rows, which the
-    buffer-shaped cotangent pads (XLA adds the pad into the sum of the
-    leaves' gradients)."""
+    """The transpose of :func:`_lane_rows`: each valid id's cotangent is
+    added into the table's block as whole buffer rows, and the block is
+    padded to the buffer's shape (XLA adds the pad into the sum of the
+    leaves' gradients).
+
+    An id's window takes its cotangent through the forward's own mask,
+    so its ``win`` rows of LANE floats hold the ``e`` floats at their
+    lanes and zeros elsewhere. One scatter per row of the window adds
+    those rows at ``r + i``: a scatter loops once per id, not once per
+    float, and sums repeated rows itself. (The TPU compiler turns a
+    scatter of two-row windows into a ``while`` loop, so the rows go one
+    at a time.) An invalid id, and a row of a window that holds none of
+    the id's floats, get a row past the block's end, which the scatter
+    drops. Rows of one element scatter as single floats, which is already
+    one update an id."""
     ids, valid, shape = res
-    g = _sum_into_block((stop - start, shape[1]), ids * e, valid, ct)
+    rows = stop - start
+    g = jnp.zeros((rows, LANE), ct.dtype)
+    at = ids * e
+    if e == 1:
+        row = jnp.where(valid, at // LANE, rows)
+        g = g.at[row, at % LANE].add(ct[:, 0], mode="drop")
+    else:
+        win, r, off, hit = _window(at, e, rows)
+        windows = jnp.sum(jnp.where(hit, ct[:, :, None], 0), axis=1)
+        for i in range(win):
+            some = valid & (off < (i + 1) * LANE) & (off + e > i * LANE)
+            g = g.at[jnp.where(some, r + i, rows)].add(
+                windows[:, i * LANE:(i + 1) * LANE], mode="drop")
     return jnp.pad(g, ((start, shape[0] - stop), (0, 0))), None, None
 
 
 _lane_rows.defvjp(_lane_rows_fwd, _lane_rows_bwd)
-
-
-def _sum_into_block(shape, at: jax.Array, valid: jax.Array,
-                    ct: jax.Array) -> jax.Array:
-    """A zero ``shape`` block (``(rows, LANE)``) with each row of ``ct``
-    (``(m, e)``) added at flat offset ``at``, rows whose ``valid`` is
-    false dropped.
-
-    One scalar scatter-add does it. Rows of one element scatter as they
-    come. Wider rows are summed per offset first: the offsets are sorted,
-    each run of one offset is summed by a segmented scan, and the runs'
-    totals are moved to the front in offset order, so the scatter's
-    indices are sorted and unique. The TPU compiler takes minutes over a
-    scatter of that many scalars in any other order, and runs it
-    slower."""
-    m, e = ct.shape
-    zeros = jnp.zeros(shape, ct.dtype)
-    if e == 1:
-        row = jnp.where(valid, at // LANE, shape[0])
-        return zeros.at[row, at % LANE].add(ct[:, 0], mode="drop")
-    index = jnp.arange(m)
-    dropped = jnp.iinfo(jnp.int32).max
-    key, perm = jax.lax.sort((jnp.where(valid, at, dropped), index),
-                             num_keys=1)
-    new = key[1:] != key[:-1]
-    first = jnp.concatenate([jnp.ones((1,), bool), new])
-    last = jnp.concatenate([new, jnp.ones((1,), bool)]) & (key != dropped)
-    _, total = jax.lax.associative_scan(_segment_add, (first, ct[perm]))
-    slot, key = jax.lax.sort((jnp.where(last, index, m), key), num_keys=1)
-    ok = (slot < m)[:, None]
-    total = total[jnp.minimum(slot, m - 1)]
-    pos = jnp.where(ok, key[:, None], 0) + jnp.arange(e)
-    # slots past the last run get a row each past the block's end: the
-    # indices stay sorted and unique, and the scatter drops them
-    row = jnp.where(ok, pos // LANE, shape[0] + index[:, None])
-    return zeros.at[row, pos % LANE].add(
-        total, indices_are_sorted=True, unique_indices=True, mode="drop")
-
-
-def _segment_add(a, b):
-    """Segmented sum: ``(starts a segment, running sum)`` pairs."""
-    first_a, sum_a = a
-    first_b, sum_b = b
-    return first_a | first_b, jnp.where(first_b[:, None], sum_b,
-                                        sum_a + sum_b)

@@ -78,6 +78,37 @@ def test_packed_lookup_matches_take(n, e):
     np.testing.assert_allclose(g, g_ref, rtol=1e-6, atol=1e-6)
 
 
+def _hot_ids(n: int, e: int, key) -> jax.Array:
+    """(K, 3096) ids: :func:`_ids`, and three rows hit a thousand times
+    each (as the numeric fields' few buckets are), one of them the last
+    and one, where the table has one, straddling two buffer rows."""
+    straddle = [i for i in range(n) if (i * e) % 128 > 128 - e][:1]
+    hot = jnp.array(([n - 1, 1] + straddle + [2])[:3] * 1000, jnp.int32)
+    return jnp.concatenate(
+        [_ids(n, e, key), jnp.broadcast_to(hot, (K, hot.size))], axis=1)
+
+
+# (12, 10) and (25, 10) fill one and exactly two buffer rows of 1-row
+# tiles, so a window cannot be two rows or is clamped to the block's two;
+# widths 2 and 64 divide a lane, 3, 10 and 127 do not
+@pytest.mark.parametrize("n,e,block_rows", [(12, 10, 1), (25, 10, 1),
+                                            (700, 2, 8), (500, 3, 1),
+                                            (4096, 10, 8), (50, 64, 8),
+                                            (90, 127, 1)])
+def test_packed_lookup_gradient_sums_repeats_exactly(n, e, block_rows):
+    """With cotangents of small integers every sum is exact in float32,
+    whatever its order, so the gradient equals ``table[ids]``'s to the
+    bit: each float lands once at its own lane, repeated ids included."""
+    buf, spec = _packed(n, e, block_rows)
+    ids = _hot_ids(n, e, jax.random.PRNGKey(n))
+    ct = jax.random.randint(jax.random.PRNGKey(2), ids.shape + (e,), -4,
+                            5).astype(jnp.float32)
+    v, g = _value_and_grad(buf, spec, ids, ct, common.lookup)
+    v_ref, g_ref = _value_and_grad(buf, spec, ids, ct, lambda t, i: t[i])
+    np.testing.assert_array_equal(v, v_ref)
+    np.testing.assert_array_equal(g, g_ref)
+
+
 @pytest.mark.parametrize("e", [1, 10, 127, 128, 200])
 def test_packed_lookup_reads_the_rows_of_the_buffer(e, monkeypatch):
     """Inside worker_map the lookup of rows narrower than a lane takes the

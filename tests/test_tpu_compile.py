@@ -17,6 +17,7 @@ keeps it until it exits. The tests skip only where no TPU compiler
 from __future__ import annotations
 
 import importlib.util
+import math
 import os
 import re
 
@@ -28,6 +29,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import make_optimizer
 from repro.core.topology import make_topology
 from repro.kernels import ops
+from repro.kernels import pack as packing
 from repro.models.deepfm import deepfm_loss, init_deepfm
 from repro.train import DecentralizedTrainer
 
@@ -149,7 +151,38 @@ def test_packed_step_compiles(one_chip, kind, monkeypatch):
     assert text.count("tpu_custom_call") >= 2
 
 
-def test_packed_deepfm_step_keeps_the_table_lane_dense(one_chip, monkeypatch):
+DEEPFM = dict(N=200_000, F=39, E=10, B=64)
+
+
+@pytest.fixture(scope="module")
+def deepfm_step(one_chip):
+    """The packed DeepFM training step's compiled text, and the stacked
+    buffer's spec, at a size where the compiler once relaid the table."""
+    N, F, E, B = (DEEPFM[k] for k in "NFEB")
+    was = ops._interpret
+    ops._interpret = lambda override=None: bool(override)
+    try:
+        opt = make_optimizer("d-adam", K=K, eta=1e-3, period=4,
+                             topology="ring", backend="pallas")
+        trainer = DecentralizedTrainer(deepfm_loss, opt)
+        params = jax.eval_shape(lambda k: init_deepfm(k, N, F, E),
+                                jax.random.PRNGKey(0))
+
+        def place(tree):
+            return jax.tree_util.tree_map(
+                lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+        state = place(jax.eval_shape(trainer.init, params))
+        batch = place({"feat_ids": jax.ShapeDtypeStruct((K, B, F),
+                                                        jnp.int32),
+                       "label": jax.ShapeDtypeStruct((K, B), jnp.int32)})
+        text = trainer.lower_step(state, batch).compile().as_text()
+    finally:
+        ops._interpret = was
+    return text, state.spec
+
+
+def test_packed_deepfm_step_keeps_the_table_lane_dense(deepfm_step):
     """The packed DeepFM training step reads and writes its (N, 10)
     embedding table only as the lane-dense rows of the packed buffer. No
     instruction is the table viewed (K, N, E) (on a TPU lane-padded to
@@ -157,23 +190,8 @@ def test_packed_deepfm_step_keeps_the_table_lane_dense(one_chip, monkeypatch):
     elements or a K-merged first-order table of K*N, and no ``while`` loop
     moves them. At this size the compiler made all of those before the
     lookup read the buffer's rows."""
-    monkeypatch.setattr(ops, "_interpret",
-                        lambda override=None: bool(override))
-    N, F, E, B = 200_000, 39, 10, 64
-    opt = make_optimizer("d-adam", K=K, eta=1e-3, period=4, topology="ring",
-                         backend="pallas")
-    trainer = DecentralizedTrainer(deepfm_loss, opt)
-    params = jax.eval_shape(lambda k: init_deepfm(k, N, F, E),
-                            jax.random.PRNGKey(0))
-
-    def place(tree):
-        return jax.tree_util.tree_map(
-            lambda x: _sds(x.shape, x.dtype, one_chip), tree)
-
-    state = place(jax.eval_shape(trainer.init, params))
-    batch = place({"feat_ids": jax.ShapeDtypeStruct((K, B, F), jnp.int32),
-                   "label": jax.ShapeDtypeStruct((K, B), jnp.int32)})
-    text = trainer.lower_step(state, batch).compile().as_text()
+    N, E = DEEPFM["N"], DEEPFM["E"]
+    text, _ = deepfm_step
     shapes = {tuple(map(int, s.split(",")))
               for s in re.findall(r"f32\[([0-9,]+)\]", text)}
     padded = {(K, N, E), (E, K * N), (K * N * E,)}
@@ -181,3 +199,23 @@ def test_packed_deepfm_step_keeps_the_table_lane_dense(one_chip, monkeypatch):
     assert not shapes & padded
     assert not [s for s in shapes if flat & set(s)]
     assert " while(" not in text
+
+
+def test_packed_deepfm_step_scatters_the_table_gradient_as_rows(deepfm_step):
+    """The (N, 10) table's gradient is added into its block of the buffer
+    as whole 128-lane rows: every scatter into the block (the K workers'
+    rows of the table, K*rows*128 elements) has a window, and no
+    instruction is that block flattened to one dimension, as a scatter of
+    single floats would need."""
+    text, spec = deepfm_step
+    (r0, r1), = [r for r, shape in zip(packing.leaf_row_ranges(spec),
+                                       spec.shapes)
+                 if shape == (K, DEEPFM["N"], DEEPFM["E"])]
+    block = K * (r1 - r0) * packing.LANE
+    scatters = [(math.prod(map(int, shape.split(","))), window)
+                for shape, window in re.findall(
+                    r"= f32\[([0-9,]+)\]\S* scatter\(.*?"
+                    r"update_window_dims=\{([0-9,]*)\}", text)]
+    into_block = [window for size, window in scatters if size == block]
+    assert into_block and all(into_block)
+    assert f"f32[{block}]" not in text
